@@ -84,7 +84,9 @@ def run_variant(arch: str, shape: str, variant: str,
     out = pathlib.Path(out_root) / variant.replace("+", "_")
     f = out / f"{arch}__{shape}.json"
     if not f.exists():
-        env = dict(os.environ, PYTHONPATH="src",
+        # the child lowers for a fake CPU mesh; pinned to the CPU it never
+        # reaches for a chip that this process holds
+        env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu",
                    REPRO_PERF_VARIANT=variant)
         r = subprocess.run(
             [sys.executable, "-m", "repro.roofline.extract", "--arch", arch,

@@ -125,6 +125,9 @@ def read_trajectory(directory: pathlib.Path | str = TRAJECTORY_DIR
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     ap.add_argument("--smoke", action="store_true",

@@ -16,11 +16,32 @@ Array = jnp.ndarray
 
 
 class CostFn(NamedTuple):
-    """value(F, C) -> D_ij elementwise; deriv(F, C) -> dD/dF elementwise."""
+    """value(F, C) -> D_ij elementwise; deriv(F, C) -> dD/dF elementwise.
+
+    ``floor`` is value(0, C), the same for every edge (1 for the exp cost).
+    """
 
     name: str
     value: Callable[[Array, Array], Array]
     deriv: Callable[[Array, Array], Array]
+    floor: float = 0.0
+
+
+def total(cost: CostFn, F: Array, C: Array, mask: Array) -> Array:
+    """Σ mask·value(F, C), summed as excess over the floor plus the floor.
+
+    The exp cost charges every edge at least exp(0) = 1, so its total
+    carries the edge count as a large constant.  Summed directly in f32,
+    the rounding at that magnitude is several ulps of the total, which is
+    the size of the two-point gradient's D(Λ+δe) − D(Λ−δe).  Summing the
+    small excesses first and adding the exact edge count once leaves one
+    rounding at the total's magnitude.  Plain jnp, so kernel bodies call
+    it too.
+    """
+    if cost.floor == 0.0:
+        return jnp.sum(mask * cost.value(F, C))
+    return (jnp.sum(mask * (cost.value(F, C) - cost.floor))
+            + cost.floor * jnp.sum(mask))
 
 
 _EXP_CLIP = 25.0     # exp cost linearized beyond z = F/C = 25
@@ -60,7 +81,7 @@ def _mm1_deriv(F: Array, C: Array) -> Array:
     return jnp.where(z <= _MM1_CLIP, base, base + h * dz) / C
 
 
-EXP = CostFn("exp", _exp_value, _exp_deriv)                       # paper §IV
+EXP = CostFn("exp", _exp_value, _exp_deriv, floor=1.0)            # paper §IV
 MM1 = CostFn("mm1", _mm1_value, _mm1_deriv)                       # paper eq. (5)
 LINEAR = CostFn("linear", lambda F, C: F / C, lambda F, C: 1.0 / C)
 QUADRATIC = CostFn("quad", lambda F, C: F * F / C, lambda F, C: 2.0 * F / C)

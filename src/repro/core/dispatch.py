@@ -217,11 +217,20 @@ def maybe_sparsify(graph, *companions):
 # lax.scan over per-phase kernels.
 # --------------------------------------------------------------------------
 
-# Per-core VMEM the fused kernel may claim for its resident state.  Real
-# v5e cores have 128 MiB; we budget well under half of it so the compiler
-# retains room for pipeline buffers and spills, and so the policy stays
-# conservative in interpret mode (where the "budget" is only a model).
-MEGAKERNEL_VMEM_BUDGET = 48 * 1024 * 1024
+# Per-core VMEM of a TPU v5e, as its compiler states it: a kernel that asks
+# for more is refused ("would exceed memory (size=134217728)").  This is the
+# one VMEM figure of the repo.  Every control-plane kernel in ``kernels/``
+# is compiled with it as its ``vmem_limit_bytes``, and
+# :func:`megakernel_fits` admits a shape only if its modelled footprint fits.
+VMEM_BYTES = 128 * 1024 * 1024
+
+# f32 [N̄p, N̄p] planes the dense megakernel holds besides φ: the F and D′
+# scratch (2), double-buffered pipeline blocks for φ⁰, the session mask,
+# the edge mask, the capacity and φ out (10), and the temporaries of the
+# phase bodies.  Compiling for a v5e at W from 1 to 64, the largest shapes
+# that compile imply 14-16 planes in all; 20 keeps a margin, and
+# ``tests/test_tpu_compile.py`` compiles the largest shape admitted.
+MEGAKERNEL_F32_PLANES = 20
 
 
 def megakernel_threshold() -> int:
@@ -289,31 +298,46 @@ def megakernel_fits(n_sessions: int, n_bar: int, itemsize: int = 4) -> bool:
     """VMEM residency check for the fused control step (DESIGN.md §17.2).
 
     The kernel keeps φ [W, N̄p, N̄p] resident at ``itemsize`` bytes (4 for
-    f32 storage, 2 for bf16) plus f32 working state: flows t [W, N̄p],
-    link flows F [N̄p, N̄p], marginal prices D′ [N̄p, N̄p], and O(W)
-    gradient/allocation vectors.  All sizes use the 128-padded node count
-    the kernel actually allocates.
+    f32 storage, 2 for bf16) plus :data:`MEGAKERNEL_F32_PLANES` f32
+    [N̄p, N̄p] planes of working state and pipeline buffers.  All sizes
+    use the 128-padded node count the kernel actually allocates, and the
+    total must fit :data:`VMEM_BYTES`, the limit the kernel compiles with.
     """
     n_pad = _round_up(max(int(n_bar), 1))
     w = max(int(n_sessions), 1)
     phi_bytes = w * n_pad * n_pad * itemsize
-    work_bytes = (2 * n_pad * n_pad + 2 * w * n_pad + 8 * w) * 4
-    return phi_bytes + work_bytes <= MEGAKERNEL_VMEM_BUDGET
+    work_bytes = MEGAKERNEL_F32_PLANES * n_pad * n_pad * 4
+    return phi_bytes + work_bytes <= VMEM_BYTES
 
 
-def use_megakernel(n_bar: int, n_sessions: int, itemsize: int = 4) -> bool:
+def use_megakernel(n_bar: int, n_sessions: int, itemsize: int = 4,
+                   sparse: bool = False) -> bool:
     """True when the fused control step should replace the stitched sweep.
 
     Conditions: n̄ clears :func:`megakernel_threshold`; the resident state
     passes :func:`megakernel_fits`; and either a real TPU backend or an
     explicit opt-in (env var / setter / ``megakernel_dispatch``) — same
     interpret-mode policy as :func:`use_kernels`.
+
+    A sparse graph never takes the megakernel on a TPU: its edge-list
+    gathers index whole node vectors, and Mosaic lowers a gather only
+    within one vreg (128 lanes), so the kernel does not compile there.
+    Such graphs run the stitched sparse kernels instead.  Forcing the
+    megakernel for a sparse graph on a TPU is an error, not a fallback.
     """
     if n_bar < megakernel_threshold():
         return False
     if not megakernel_fits(n_sessions, n_bar, itemsize):
         return False
-    return _megakernel_explicit() or jax.default_backend() == "tpu"
+    on_tpu = jax.default_backend() == "tpu"
+    if sparse and on_tpu:
+        if _megakernel_explicit():
+            raise NotImplementedError(
+                "the sparse megakernel does not compile for a TPU (its "
+                "gathers span more than one vreg); leave the megakernel "
+                "off for sparse graphs and the stitched sparse kernels run")
+        return False
+    return _megakernel_explicit() or on_tpu
 
 
 # --------------------------------------------------------------------------

@@ -24,10 +24,15 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.lax import Precision
 
-from . import dispatch
+from . import costs, dispatch
 from .costs import CostFn
 from .graph import CECGraph, CECGraphSparse
+
+# full f32 contractions: a TPU runs a default-precision f32 dot as one
+# bf16 pass, far coarser than the control plane's f32 parity contract
+HIGHEST = Precision.HIGHEST
 
 Array = jnp.ndarray
 
@@ -55,7 +60,8 @@ def propagate(graph: CECGraph | CECGraphSparse, phi, lam: Array) -> Array:
             return flow_step_op(t, phi, inject, interpret=interpret), None
     else:
         def step(t, _):
-            return inject + jnp.einsum("wi,wij->wj", t, phi), None
+            return inject + jnp.einsum("wi,wij->wj", t, phi,
+                                       precision=HIGHEST), None
 
     t, _ = jax.lax.scan(step, inject, None, length=graph.depth_max)
     return t
@@ -71,7 +77,7 @@ def link_flows(graph: CECGraph | CECGraphSparse, phi, t: Array):
         from . import sparse
 
         return sparse.link_flow_slots(graph, phi, t)
-    return jnp.einsum("wi,wij->ij", t, phi)
+    return jnp.einsum("wi,wij->ij", t, phi, precision=HIGHEST)
 
 
 def total_cost(graph: CECGraph | CECGraphSparse, cost: CostFn, phi,
@@ -83,7 +89,7 @@ def total_cost(graph: CECGraph | CECGraphSparse, cost: CostFn, phi,
         return sparse.total_cost(graph, cost, phi, lam)
     t = propagate(graph, phi, lam)
     F = link_flows(graph, phi, t)
-    return jnp.sum(graph.edge_mask * cost.value(F, graph.capacity))
+    return costs.total(cost, F, graph.capacity, graph.edge_mask)
 
 
 def cost_and_state(graph: CECGraph | CECGraphSparse, cost: CostFn, phi,
@@ -95,5 +101,5 @@ def cost_and_state(graph: CECGraph | CECGraphSparse, cost: CostFn, phi,
         return sparse.cost_and_state(graph, cost, phi, lam)
     t = propagate(graph, phi, lam)
     F = link_flows(graph, phi, t)
-    D = jnp.sum(graph.edge_mask * cost.value(F, graph.capacity))
+    D = costs.total(cost, F, graph.capacity, graph.edge_mask)
     return D, t, F

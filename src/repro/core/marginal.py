@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from .costs import CostFn
+from .flow import HIGHEST
 from .graph import CECGraph, CECGraphSparse
 
 Array = jnp.ndarray
@@ -47,7 +48,8 @@ def marginals(graph: CECGraph | CECGraphSparse, cost: CostFn, phi, t: Array,
 
     def step(r, _):
         # r_i(w) = Σ_j φ_ij (Dp_ij + r_j);  sinks have no out-edges → stay 0
-        nxt = jnp.einsum("wij,wij->wi", phi, mask * (Dp[None] + r[:, None, :]))
+        nxt = jnp.einsum("wij,wij->wi", phi, mask * (Dp[None] + r[:, None, :]),
+                         precision=HIGHEST)
         return nxt, None
 
     zero = jnp.zeros_like(t)

@@ -452,7 +452,8 @@ def step(problem: Problem, config: SolverConfig, state: SolverState,
         out = _learned_step(problem, config, state, task_utilities)
     else:
         itemsize = 2 if dispatch.megakernel_phi_dtype() == "bfloat16" else 4
-        if dispatch.use_megakernel(graph.n_bar, graph.n_sessions, itemsize):
+        if dispatch.use_megakernel(graph.n_bar, graph.n_sessions, itemsize,
+                                   sparse=isinstance(graph, CECGraphSparse)):
             mode = "megakernel"
             out = _megakernel_step(problem, config, state, task_utilities)
         else:
@@ -483,7 +484,11 @@ def _trace_dispatch(mode: str, graph) -> None:
             f"solver.dispatch:{mode}", cat="dispatch",
             args={"mode": mode, "n_bar": int(graph.n_bar),
                   "n_sessions": int(graph.n_sessions),
-                  "sparse": isinstance(graph, CECGraphSparse)})
+                  "sparse": isinstance(graph, CECGraphSparse),
+                  # whether the per-phase Pallas kernels serve the jnp
+                  # body's propagation and EG update (mode "sampled"/
+                  # "learned"): "stitched" rather than plain jnp
+                  "kernels": dispatch.use_kernels(graph.n_bar)})
 
 
 def step_with_etas(problem: Problem, config: SolverConfig,
@@ -499,7 +504,9 @@ def step_with_etas(problem: Problem, config: SolverConfig,
     """
     graph = problem.graph
     if (dispatch.use_kernels(graph.n_bar)
-            or dispatch.use_megakernel(graph.n_bar, graph.n_sessions)):
+            or dispatch.use_megakernel(
+                graph.n_bar, graph.n_sessions,
+                sparse=isinstance(graph, CECGraphSparse))):
         raise NotImplementedError(
             "step_with_etas traces η through the OMD update, but the "
             "Pallas kernel paths (per-phase and megakernel alike) need a "

@@ -32,8 +32,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import dispatch
+from . import costs, dispatch
 from .costs import CostFn
+from .flow import HIGHEST
 from .graph import CECGraph, CECGraphSparse, SparsePhi
 
 Array = jnp.ndarray
@@ -156,9 +157,16 @@ def propagate(graph: CECGraphSparse, phi: SparsePhi, lam: Array) -> Array:
 def link_flow_slots(graph: CECGraphSparse, phi: SparsePhi,
                     t: Array) -> SparsePhi:
     """Per-edge total flow F (eq. (4)) in the slot layout."""
-    rows = jnp.einsum("wi,wid->id", t, phi.rows)
-    src = jnp.einsum("w,wd->d", t[:, graph.src], phi.src)
+    rows = jnp.einsum("wi,wid->id", t, phi.rows, precision=HIGHEST)
+    src = jnp.einsum("w,wd->d", t[:, graph.src], phi.src,
+                     precision=HIGHEST)
     return SparsePhi(rows=rows, src=src)
+
+
+def _slot_cost(graph: CECGraphSparse, cost: CostFn, F: SparsePhi) -> Array:
+    return (costs.total(cost, F.rows, graph.capacity, graph.edge_mask)
+            + costs.total(cost, F.src, graph.src_capacity,
+                          graph.src_edge_mask))
 
 
 def total_cost(graph: CECGraphSparse, cost: CostFn, phi: SparsePhi,
@@ -166,9 +174,7 @@ def total_cost(graph: CECGraphSparse, cost: CostFn, phi: SparsePhi,
     """Σ_{e∈Ē} D_e(F_e, C_e) — identical edge set to the dense sum."""
     t = propagate(graph, phi, lam)
     F = link_flow_slots(graph, phi, t)
-    return (jnp.sum(graph.edge_mask * cost.value(F.rows, graph.capacity))
-            + jnp.sum(graph.src_edge_mask
-                      * cost.value(F.src, graph.src_capacity)))
+    return _slot_cost(graph, cost, F)
 
 
 def cost_and_state(graph: CECGraphSparse, cost: CostFn, phi: SparsePhi,
@@ -176,10 +182,7 @@ def cost_and_state(graph: CECGraphSparse, cost: CostFn, phi: SparsePhi,
     """(total cost, t, F-slots) in one pass — the routing-iteration bundle."""
     t = propagate(graph, phi, lam)
     F = link_flow_slots(graph, phi, t)
-    D = (jnp.sum(graph.edge_mask * cost.value(F.rows, graph.capacity))
-         + jnp.sum(graph.src_edge_mask * cost.value(F.src,
-                                                    graph.src_capacity)))
-    return D, t, F
+    return _slot_cost(graph, cost, F), t, F
 
 
 # ---------------------------------------------------------------------------

@@ -44,9 +44,10 @@ cheap 2D transpose for these shapes) and the sort inside the exact
 projection is an O(M²) stable rank sort — ``jnp.sort`` does not lower
 inside a TPU kernel body.  The sparse variant keeps the session rate
 vector 1-D and gathers with ``jnp.take`` over flattened (node·stride +
-slot) ids exactly like ``flow_step_sparse.py``; interpret mode is the
-only CI-exercised mode, the TPU path additionally relies on Mosaic's
-dynamic-gather lowering.
+slot) ids.  Mosaic lowers a gather only within one vreg, so the sparse
+variant runs in interpret mode only: ``dispatch.use_megakernel`` never
+picks it on a TPU, where sparse graphs take the stitched kernels.
+Every kernel compiles with ``vmem_limit_bytes=dispatch.VMEM_BYTES``.
 """
 from __future__ import annotations
 
@@ -56,6 +57,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.costs import total
+from repro.core.dispatch import VMEM_BYTES
 
 NEG = -1e30
 BIG = 1e30
@@ -81,6 +85,14 @@ def _col(row):
 def _row(col):
     """[M, 1] → [1, M] (same trick, other axis)."""
     return jnp.sum(_eye(col.shape[0], col.dtype) * col, axis=0, keepdims=True)
+
+
+def _mm(a, b):
+    """[M, K] @ [K, N] in full f32 (a TPU's default precision would run
+    an f32 dot as one bf16 pass)."""
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
 def _eg(phi, delta, mask, eta):
@@ -183,7 +195,7 @@ def _dense_kernel(lam_ref, phi0_ref, omask_ref, emask_ref, cap_ref, tau_ref,
     # --- first visit: seed the VMEM-resident φ and the gradient scratch
     @pl.when((p == 0) & (k == 0) & (ph == 0))
     def _seed_phi():
-        pl.store(phi_s, wsl, phi0_ref[...].astype(phi_s.dtype))
+        phi_s[wsl] = phi0_ref[...].astype(phi_s.dtype)
 
     @pl.when((p == 0) & (k == 0) & (ph == 0) & (w == 0))
     def _seed_g():
@@ -205,14 +217,12 @@ def _dense_kernel(lam_ref, phi0_ref, omask_ref, emask_ref, cap_ref, tau_ref,
     # --- phase 0: Jacobi flow relaxation + link-flow accumulation
     @pl.when(ph == 0)
     def _flow():
-        phi_w = pl.load(phi_s, wsl)[0].astype(jnp.float32)
+        phi_w = phi_s[wsl][0].astype(jnp.float32)
         lam_w = jnp.sum(jnp.where(widx == w, lam_s[...], 0.0))
         inject = jnp.where(_iota((1, np_), 1) == src, lam_w, 0.0)
 
         def relax(_, t):
-            return inject + jax.lax.dot_general(
-                t, phi_w, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            return inject + _mm(t, phi_w)
 
         t = jax.lax.fori_loop(0, depth, relax, inject)
 
@@ -229,28 +239,25 @@ def _dense_kernel(lam_ref, phi0_ref, omask_ref, emask_ref, cap_ref, tau_ref,
 
     @pl.when((ph == 1) & (k < K))
     def _update():
-        phi_w = pl.load(phi_s, wsl)[0].astype(jnp.float32)
+        phi_w = phi_s[wsl][0].astype(jnp.float32)
         mask_w = omask_ref[0]
         pm = phi_w * mask_w
         dp = dp_s[...]
         ones = jnp.ones((np_, 1), jnp.float32)
-        b = jax.lax.dot_general(pm * dp, ones, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        b = _mm(pm * dp, ones)
 
         def back(_, r):
-            return b + jax.lax.dot_general(pm, r, (((1,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
+            return b + _mm(pm, r)
 
         r = jax.lax.fori_loop(0, depth, back, jnp.zeros_like(b))
         delta_w = mask_w * (dp + _row(r))
-        pl.store(phi_s, wsl,
-                 _eg(phi_w, delta_w, mask_w, eta_inner)[None].astype(
-                     phi_s.dtype))
+        phi_s[wsl] = _eg(phi_w, delta_w, mask_w, eta_inner)[None].astype(
+            phi_s.dtype)
 
     # --- phase 1, k == K: observe the cost, fold the two-point term
     @pl.when((ph == 1) & (w == 0) & (k == K))
     def _observe():
-        D = jnp.sum(emask_ref[...] * cost.value(f_s[...], cap_ref[...]))
+        D = total(cost, f_s[...], cap_ref[...], emask_ref[...])
         d_s[...] = jnp.zeros_like(d_s) + D
 
         @pl.when(p < P - 1)
@@ -262,7 +269,7 @@ def _dense_kernel(lam_ref, phi0_ref, omask_ref, emask_ref, cap_ref, tau_ref,
     # --- emit: per-w blocked φ at its last visit, rows at the final step
     @pl.when((p == P - 1) & (k == K) & (ph == 1))
     def _emit_phi():
-        phi_o[...] = pl.load(phi_s, wsl).astype(phi_o.dtype)
+        phi_o[...] = phi_s[wsl].astype(phi_o.dtype)
 
     @pl.when((p == P - 1) & (k == K) & (ph == 1) & (w == W - 1))
     def _emit_rows():
@@ -274,7 +281,7 @@ def _dense_kernel(lam_ref, phi0_ref, omask_ref, emask_ref, cap_ref, tau_ref,
 def control_step_dense(lam, phi, out_mask, edge_mask, capacity, task_u, tot,
                        *, depth_max, src, k_iters, delta, eta_outer,
                        eta_inner, cost, phi_dtype=jnp.float32,
-                       interpret=False):
+                       interpret):
     """Padded-operand dense megakernel (callers go through ``ops``).
 
     ``lam``/``tot`` (1, Wp); ``phi``/``out_mask`` [W, Np, Np];
@@ -311,6 +318,7 @@ def control_step_dense(lam, phi, out_mask, edge_mask, capacity, task_u, tot,
             pltpu.VMEM((1, wp), jnp.float32),        # admission Λ_p
             pltpu.VMEM((1, wp), jnp.float32),        # observed cost D
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BYTES),
         interpret=interpret,
     )(lam, phi, out_mask, edge_mask, capacity, task_u, tot)
 
@@ -339,13 +347,12 @@ def _sparse_kernel(lam_ref, rows0_ref, src0_ref, omask_ref, smask_ref,
     lam_total = jnp.max(tot_ref[...])
     widx = _iota((1, wp), 1)
     nidx = _iota((1, np_), 1)[0]                     # [Np] node ids (2D-born)
-    wsl3 = (pl.ds(w, 1), slice(None), slice(None))
-    wsl2 = (pl.ds(w, 1), slice(None))
+    wsl = (pl.ds(w, 1), slice(None), slice(None))
 
     @pl.when((p == 0) & (k == 0) & (ph == 0))
     def _seed_phi():
-        pl.store(rows_s, wsl3, rows0_ref[...].astype(rows_s.dtype))
-        pl.store(srcphi_s, wsl2, src0_ref[...].astype(srcphi_s.dtype))
+        rows_s[wsl] = rows0_ref[...].astype(rows_s.dtype)
+        srcphi_s[wsl] = src0_ref[...].astype(srcphi_s.dtype)
 
     @pl.when((p == 0) & (k == 0) & (ph == 0) & (w == 0))
     def _seed_g():
@@ -366,22 +373,20 @@ def _sparse_kernel(lam_ref, rows0_ref, src0_ref, omask_ref, smask_ref,
     # --- phase 0: Jacobi relaxation over edge lists (cf. sparse.propagate)
     @pl.when(ph == 0)
     def _flow():
-        rows_w = pl.load(rows_s, wsl3)[0].astype(jnp.float32)  # [Np, D]
-        src_w = pl.load(srcphi_s, wsl2).astype(jnp.float32)    # (1, Ds)
+        rows_w = rows_s[wsl][0].astype(jnp.float32)           # [Np, D]
+        src_w = srcphi_s[wsl][0].astype(jnp.float32)           # (1, Ds)
         lam_w = jnp.sum(jnp.where(widx == w, lam_s[...], 0.0))
         # base inflow: exogenous injection at S plus the admission flow
         # λ_w·φ_S scattered onto the S→D(1) heads by the (Ds, Np) matmul
         # scatter built in ops.py (no in-kernel scatter on TPU)
-        admit = lam_w * src_w * smask_ref[...]                 # (1, Ds)
-        scat = jax.lax.dot_general(admit, smat_ref[...],
-                                   (((1,), (0,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
+        admit = lam_w * src_w * smask_ref[0]                   # (1, Ds)
+        scat = _mm(admit, smat_ref[...])
         inject = jnp.where(nidx == src, lam_w, 0.0)            # [Np]
         base = inject + scat[0]
         flat = rows_w.reshape(-1)
         pv = jnp.take(flat, insrc_ref[...] * dmax + inslot_ref[...])
         psink = jnp.take(flat, nidx * dmax + sink_ref[0])      # [Np]
-        dep_w = dep_ref[0]                                     # [Np]
+        dep_w = dep_ref[0][0]                                  # [Np]
         on_sink = nidx == (n_phys + 1 + w)
 
         def relax(_, t):
@@ -409,10 +414,10 @@ def _sparse_kernel(lam_ref, rows0_ref, src0_ref, omask_ref, smask_ref,
 
     @pl.when((ph == 1) & (k < K))
     def _update():
-        rows_w = pl.load(rows_s, wsl3)[0].astype(jnp.float32)
-        src_w = pl.load(srcphi_s, wsl2).astype(jnp.float32)
+        rows_w = rows_s[wsl][0].astype(jnp.float32)
+        src_w = srcphi_s[wsl][0].astype(jnp.float32)
         mask_w = omask_ref[0]                                  # [Np, D]
-        smask_w = smask_ref[...]                               # (1, Ds)
+        smask_w = smask_ref[0]                                 # (1, Ds)
         nbr = nbr_ref[...]
         snbr = snbr_ref[...]
         dpr = dp_s[...]
@@ -427,18 +432,15 @@ def _sparse_kernel(lam_ref, rows0_ref, src0_ref, omask_ref, smask_ref,
                               jnp.zeros((np_,), jnp.float32))
         delta_rows = mask_w * (dpr + jnp.take(r, nbr))
         delta_src = smask_w * (dps + jnp.take(r, snbr))
-        pl.store(rows_s, wsl3,
-                 _eg(rows_w, delta_rows, mask_w, eta_inner)[None].astype(
-                     rows_s.dtype))
-        pl.store(srcphi_s, wsl2,
-                 _eg(src_w, delta_src, smask_w, eta_inner).astype(
-                     srcphi_s.dtype))
+        rows_s[wsl] = _eg(rows_w, delta_rows, mask_w, eta_inner)[None].astype(
+            rows_s.dtype)
+        srcphi_s[wsl] = _eg(src_w, delta_src, smask_w, eta_inner)[None].astype(
+            srcphi_s.dtype)
 
     @pl.when((ph == 1) & (w == 0) & (k == K))
     def _observe():
-        D = (jnp.sum(emask_ref[...] * cost.value(f_s[...], cap_ref[...]))
-             + jnp.sum(semask_ref[...] * cost.value(fsrc_s[...],
-                                                    scap_ref[...])))
+        D = (total(cost, f_s[...], cap_ref[...], emask_ref[...])
+             + total(cost, fsrc_s[...], scap_ref[...], semask_ref[...]))
         d_s[...] = jnp.zeros_like(d_s) + D
 
         @pl.when(p < P - 1)
@@ -449,8 +451,8 @@ def _sparse_kernel(lam_ref, rows0_ref, src0_ref, omask_ref, smask_ref,
 
     @pl.when((p == P - 1) & (k == K) & (ph == 1))
     def _emit_phi():
-        rows_o[...] = pl.load(rows_s, wsl3).astype(rows_o.dtype)
-        src_o[...] = pl.load(srcphi_s, wsl2).astype(src_o.dtype)
+        rows_o[...] = rows_s[wsl].astype(rows_o.dtype)
+        src_o[...] = srcphi_s[wsl].astype(src_o.dtype)
 
     @pl.when((p == P - 1) & (k == K) & (ph == 1) & (w == W - 1))
     def _emit_rows():
@@ -464,7 +466,7 @@ def control_step_sparse(lam, rows, src_phi, out_mask, src_out_mask, deploy,
                         nbr, src_nbr, sink_slot, in_src, in_slot, in_mask,
                         smat, task_u, tot, *, depth_max, src, n_phys,
                         k_iters, delta, eta_outer, eta_inner, cost,
-                        phi_dtype=jnp.float32, interpret=False):
+                        phi_dtype=jnp.float32, interpret):
     """Padded-operand sparse megakernel (callers go through ``ops``).
 
     Slot layout follows ``CECGraphSparse``; ``smat`` is the (Ds, Np)
@@ -472,14 +474,14 @@ def control_step_sparse(lam, rows, src_phi, out_mask, src_out_mask, deploy,
     φ'.src, ĝ, D-row), all f32.
     """
     W, np_, dmax = rows.shape
-    dsp = src_phi.shape[1]
+    dsp = src_phi.shape[2]
     wp = lam.shape[1]
     grid = (2 * W + 1, k_iters + 1, 2, W)
     row = pl.BlockSpec(lam.shape, lambda p, k, ph, w: (0, 0))
     tau_row = pl.BlockSpec(task_u.shape, lambda p, k, ph, w: (0, 0))
     per_w3 = pl.BlockSpec((1, np_, dmax), lambda p, k, ph, w: (w, 0, 0))
-    per_w_src = pl.BlockSpec((1, dsp), lambda p, k, ph, w: (w, 0))
-    per_w_node = pl.BlockSpec((1, np_), lambda p, k, ph, w: (w, 0))
+    per_w_src = pl.BlockSpec((1, 1, dsp), lambda p, k, ph, w: (w, 0, 0))
+    per_w_node = pl.BlockSpec((1, 1, np_), lambda p, k, ph, w: (w, 0, 0))
     full = pl.BlockSpec((np_, dmax), lambda p, k, ph, w: (0, 0))
     full_src = pl.BlockSpec((1, dsp), lambda p, k, ph, w: (0, 0))
     full_node = pl.BlockSpec((1, np_), lambda p, k, ph, w: (0, 0))
@@ -499,13 +501,13 @@ def control_step_sparse(lam, rows, src_phi, out_mask, src_out_mask, deploy,
         out_shape=[
             jax.ShapeDtypeStruct((1, wp), jnp.float32),
             jax.ShapeDtypeStruct((W, np_, dmax), jnp.float32),
-            jax.ShapeDtypeStruct((W, dsp), jnp.float32),
+            jax.ShapeDtypeStruct((W, 1, dsp), jnp.float32),
             jax.ShapeDtypeStruct((1, wp), jnp.float32),
             jax.ShapeDtypeStruct((1, wp), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((W, np_, dmax), phi_dtype),   # φ rows — resident
-            pltpu.VMEM((W, dsp), phi_dtype),         # φ source row
+            pltpu.VMEM((W, 1, dsp), phi_dtype),      # φ source row
             pltpu.VMEM((np_, dmax), jnp.float32),    # F slot accumulator
             pltpu.VMEM((1, dsp), jnp.float32),       # F source slots
             pltpu.VMEM((np_, dmax), jnp.float32),    # slot prices D'
@@ -514,6 +516,7 @@ def control_step_sparse(lam, rows, src_phi, out_mask, src_out_mask, deploy,
             pltpu.VMEM((1, wp), jnp.float32),        # admission Λ_p
             pltpu.VMEM((1, wp), jnp.float32),        # observed cost D
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BYTES),
         interpret=interpret,
     )(lam, rows, src_phi, out_mask, src_out_mask, deploy, edge_mask,
       capacity, src_edge_mask, src_capacity, nbr, src_nbr, sink_slot,

@@ -5,7 +5,8 @@ the running (max, denom, acc) state lives in VMEM scratch across kv blocks.
 Fully-masked causal blocks are skipped with ``pl.when`` (≈2× prefill win).
 BlockSpecs keep one (bq×hd) query tile + one (bk×hd) KV tile + the f32
 accumulator in VMEM: for bq=bk=512, hd=128 that is ≈0.9 MB — well under
-the ~16 MB v5e VMEM budget, and all matmul dims are 128-multiples (MXU).
+the compiler's default 16 MiB scoped-VMEM limit (a v5e core has 128 MiB,
+``core.dispatch.VMEM_BYTES``), and all matmul dims are 128-multiples.
 """
 from __future__ import annotations
 

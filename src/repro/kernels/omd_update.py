@@ -22,6 +22,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.dispatch import VMEM_BYTES
 
 NEG = -1e30
 
@@ -39,7 +42,7 @@ def _omd_kernel(phi_ref, delta_ref, mask_ref, o_ref, *, eta: float):
 
 
 def omd_update(phi, delta, mask, eta: float, *, br: int = 128,
-               interpret: bool = False):
+               interpret: bool):
     """phi, delta, mask [W, N, N] → updated phi.  Rows N multiple of br."""
     W, N, _ = phi.shape
     br = min(br, N)
@@ -51,5 +54,6 @@ def omd_update(phi, delta, mask, eta: float, *, br: int = 128,
         in_specs=[spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(phi.shape, phi.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BYTES),
         interpret=interpret,
     )(phi, delta, mask)

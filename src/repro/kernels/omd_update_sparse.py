@@ -20,6 +20,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.dispatch import VMEM_BYTES
 
 NEG = -1e30
 
@@ -37,7 +40,7 @@ def _omd_sparse_kernel(phi_ref, delta_ref, mask_ref, o_ref, *, eta: float):
 
 
 def omd_update_sparse(phi, delta, mask, eta: float, *, br: int = 128,
-                      interpret: bool = False):
+                      interpret: bool):
     """phi, delta, mask [W, R, C] → updated phi.  R multiple of br."""
     W, R, C = phi.shape
     br = min(br, R)
@@ -49,5 +52,6 @@ def omd_update_sparse(phi, delta, mask, eta: float, *, br: int = 128,
         in_specs=[spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(phi.shape, phi.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BYTES),
         interpret=interpret,
     )(phi, delta, mask)

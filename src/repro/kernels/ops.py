@@ -1,15 +1,17 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Each op pads its operands to hardware-aligned tiles, invokes the kernel
-(``interpret=True`` on CPU — the TPU path flips the flag), and slices the
-padding back off.  The control plane consults ``core.dispatch``:
-``flow_step_op`` / ``omd_update_op`` are invoked by ``core.flow.propagate``
-and ``core.routing.omd_step`` whenever ``dispatch.use_kernels(n_bar)``
-holds (threshold cleared on TPU, or an explicit override), with
-``interpret=dispatch.kernel_interpret()`` (True off-TPU).  Padding rules: both node axes go to multiples of 128 with
-zeros — zero-padded φ rows contribute nothing to ``flow_step`` accumulation,
-and all-zero-mask rows in ``omd_update`` fall through to the input φ before
-being sliced off, so padding is exact.
+and slices the padding back off.  ``interpret`` is a required keyword:
+``core.dispatch.kernel_interpret()`` is the one place that decides it
+(True off-TPU), and an op that defaulted it would run the Pallas
+interpreter on the chip for any caller that forgot it.  The control
+plane consults ``core.dispatch``: ``flow_step_op`` / ``omd_update_op``
+are invoked by ``core.flow.propagate`` and ``core.routing.omd_step``
+whenever ``dispatch.use_kernels(n_bar)`` holds (threshold cleared on
+TPU, or an explicit override).  Padding rules: both node axes go to
+multiples of 128 with zeros — zero-padded φ rows contribute nothing to
+``flow_step`` accumulation, and all-zero-mask rows in ``omd_update`` fall
+through to the input φ before being sliced off, so padding is exact.
 """
 from __future__ import annotations
 
@@ -59,8 +61,8 @@ def _round_up(n: int, mult: int = 128) -> int:
 
 @partial(jax.jit, static_argnames=("causal", "q_offset", "kv_len",
                                    "interpret"))
-def flash_attention_op(q, k, v, causal=True, q_offset=0, kv_len=None,
-                       interpret=True):
+def flash_attention_op(q, k, v, causal=True, q_offset=0, kv_len=None, *,
+                       interpret):
     """Padded/sliced flash attention; q [B,H,S,hd], k/v [B,KH,T,hd]."""
     S, T = q.shape[2], k.shape[2]
     kv_len = T if kv_len is None else kv_len
@@ -75,16 +77,16 @@ def flash_attention_op(q, k, v, causal=True, q_offset=0, kv_len=None,
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def flow_step_op(t, phi, inject, interpret=True):
+def flow_step_op(t, phi, inject, *, interpret):
     N = t.shape[1]
-    tp = _pad_to(t, 1, 128)
-    ip = _pad_to(inject, 1, 128)
+    tp = _pad_to(t, 1, 128)[:, None, :]
+    ip = _pad_to(inject, 1, 128)[:, None, :]
     pp = _pad_to(_pad_to(phi, 1, 128), 2, 128)
-    return flow_step(tp, pp, ip, interpret=interpret)[:, :N]
+    return flow_step(tp, pp, ip, interpret=interpret)[:, 0, :N]
 
 
 @partial(jax.jit, static_argnames=("eta", "interpret"))
-def omd_update_op(phi, delta, mask, eta, interpret=True):
+def omd_update_op(phi, delta, mask, eta, *, interpret):
     N = phi.shape[1]
     pp = _pad_to(_pad_to(phi, 1, 128), 2, 128)
     dp = _pad_to(_pad_to(delta, 1, 128), 2, 128)
@@ -94,28 +96,28 @@ def omd_update_op(phi, delta, mask, eta, interpret=True):
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def flow_step_sparse_op(t, rows, base, in_src, in_slot, in_mask,
-                        interpret=True):
+def flow_step_sparse_op(t, rows, base, in_src, in_slot, in_mask, *,
+                        interpret):
     """Padded/sliced sparse relaxation step (see flow_step_sparse.py).
 
-    Pads the node axis to 128 and both slot axes (d_max, d_in_max) to 128.
-    Slot ids stay valid under padding because ``in_slot`` indexes within
-    its row (the kernel flattens with the *padded* slot stride); padded
-    in-entries carry mask 0 and point at (0, 0).
+    Gathers the masked in-edge routing shares pv [W, N, Din] here, in XLA,
+    then lays the in-lists out nodes-on-lanes ([Din, N], Din padded to 8,
+    N to 128) with each tail split into its lane and 128-node chunk.
+    Padded in-slots carry pv 0 and point at node 0.
     """
     N = t.shape[1]
-    tp = _pad_to(t, 1, 128)
-    bp = _pad_to(base, 1, 128)
-    rp = _pad_to(_pad_to(rows, 1, 128), 2, 128)
-    sp = _pad_to(_pad_to(in_src, 0, 128), 1, 128)
-    slp = _pad_to(_pad_to(in_slot, 0, 128), 1, 128)
-    mp = _pad_to(_pad_to(in_mask, 0, 128), 1, 128)
-    return flow_step_sparse(tp, rp, bp, sp, slp, mp,
-                            interpret=interpret)[:, :N]
+    pv = rows[:, in_src, in_slot] * in_mask                   # [W, N, Din]
+    pvt = _pad_to(_pad_to(jnp.swapaxes(pv, 1, 2), 1, 8), 2, 128)
+    src = _pad_to(_pad_to(in_src.T, 0, 8), 1, 128)
+    tp = _pad_to(t, 1, 128)[:, None, :]
+    bp = _pad_to(base, 1, 128)[:, None, :]
+    out = flow_step_sparse(tp, pvt, bp, src % 128, src // 128,
+                           interpret=interpret)
+    return out[:, 0, :N]
 
 
 @partial(jax.jit, static_argnames=("eta", "interpret"))
-def omd_update_sparse_op(phi, delta, mask, eta, interpret=True):
+def omd_update_sparse_op(phi, delta, mask, eta, *, interpret):
     """Padded/sliced sparse EG update over [W, R, C] edge-slot rows."""
     R, C = phi.shape[1], phi.shape[2]
     pp = _pad_to(_pad_to(phi, 1, 128), 2, 128)
@@ -129,8 +131,8 @@ def omd_update_sparse_op(phi, delta, mask, eta, interpret=True):
                                    "eta_inner", "cost", "phi_dtype",
                                    "interpret"))
 def control_step_op(lam, phi, task_u, lam_total, graph, k_iters, delta,
-                    eta_outer, eta_inner, cost, phi_dtype="float32",
-                    interpret=True):
+                    eta_outer, eta_inner, cost, phi_dtype="float32", *,
+                    interpret):
     """Padded/sliced one-kernel fused control step, dense layout.
 
     ``lam`` [W], ``phi`` [W, Nb, Nb], ``task_u`` [2W] (the measured task
@@ -162,7 +164,7 @@ def control_step_op(lam, phi, task_u, lam_total, graph, k_iters, delta,
                                    "interpret"))
 def control_step_sparse_op(lam, rows, src_phi, task_u, lam_total, graph,
                            k_iters, delta, eta_outer, eta_inner, cost,
-                           phi_dtype="float32", interpret=True):
+                           phi_dtype="float32", *, interpret):
     """Padded/sliced one-kernel fused control step, sparse slot layout.
 
     ``rows``/``src_phi`` are the ``SparsePhi`` parts, ``graph`` a
@@ -181,10 +183,10 @@ def control_step_sparse_op(lam, rows, src_phi, task_u, lam_total, graph,
     taup = _pad_to(task_u[None, :], 1, 128)
     tot = jnp.zeros_like(lp) + lam_total
     rp = _pad_axis_to(_pad_to(rows, 2, 128), 1, Np)
-    sp = _pad_to(src_phi, 1, 128)
+    sp = _pad_to(src_phi, 1, 128)[:, None, :]
     omp = _pad_axis_to(_pad_to(graph.out_mask, 2, 128), 1, Np)
-    smp = _pad_to(graph.src_out_mask, 1, 128)
-    dep = _pad_axis_to(graph.deploy.astype(jnp.float32), 1, Np)
+    smp = _pad_to(graph.src_out_mask, 1, 128)[:, None, :]
+    dep = _pad_axis_to(graph.deploy.astype(jnp.float32), 1, Np)[:, None, :]
     emp = _pad_axis_to(_pad_to(graph.edge_mask, 1, 128), 0, Np)
     cap = _pad_axis_to(_pad_to(graph.capacity, 1, 128, 1.0), 0, Np, 1.0)
     semp = _pad_to(graph.src_edge_mask[None, :], 1, 128)
@@ -207,12 +209,12 @@ def control_step_sparse_op(lam, rows, src_phi, task_u, lam_total, graph,
         src=graph.src, n_phys=graph.n_phys, k_iters=k_iters, delta=delta,
         eta_outer=eta_outer, eta_inner=eta_inner, cost=cost, phi_dtype=dt,
         interpret=interpret)
-    return (lam_o[0, :W], rows_o[:, :N, :D], src_o[:, :Ds], g_o[0, :W],
+    return (lam_o[0, :W], rows_o[:, :N, :D], src_o[:, 0, :Ds], g_o[0, :W],
             d_o[0, 0])
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def mamba_scan_op(u, dt, A, Bm, Cm, interpret=True):
+def mamba_scan_op(u, dt, A, Bm, Cm, *, interpret):
     """Padded chunkwise SSM scan; pads di→128-multiple, S→chunk multiple."""
     B, S, di = u.shape
     ck = 128 if S >= 128 else S

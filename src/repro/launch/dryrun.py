@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, SHAPES, applicable, get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import dp_axes, make_production_mesh
 from repro.parallel.annotate import activation_sharding
 from repro.models import model as M
@@ -36,9 +37,6 @@ from repro.roofline.analysis import model_flops
 from repro.roofline.hlo import parse_collectives
 from repro.train.steps import make_decode_step, make_train_step
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 
 def _sds(tree):
@@ -207,6 +205,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, outdir: pathlib.Path,
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=tuple(SHAPES))

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import build_random_cec
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 from repro.models.config import ModelConfig
 from repro.serve import CECRouter, InferenceEngine, Request
@@ -40,6 +41,7 @@ def version_ladder() -> list[ModelConfig]:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--intervals", type=int, default=12)
     ap.add_argument("--requests", type=int, default=24)

@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
 from repro.data import SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 from repro.optim import AdamW
 from repro.train.runner import RunnerConfig, TrainRunner
@@ -24,6 +25,7 @@ from repro.train.steps import make_train_step
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="smollm-135m")
     ap.add_argument("--smoke", action="store_true")

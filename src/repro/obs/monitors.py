@@ -61,7 +61,7 @@ import jax.numpy as jnp
 
 from repro.core import routing as _routing
 from repro.core import sparse as _sparse
-from repro.core.flow import link_flows, propagate
+from repro.core.flow import HIGHEST, link_flows, propagate
 from repro.core.graph import CECGraphSparse
 from repro.core.problem import Problem, resolve_cost
 
@@ -140,7 +140,8 @@ def _one_jacobi(graph, phi, lam, t):
         wi = jnp.arange(graph.n_sessions)
         return t_new.at[wi, graph.sinks].set(
             _sparse._sink_inflow(graph, phi.rows, t))
-    return graph.injection(lam) + jnp.einsum("wi,wij->wj", t, phi)
+    return graph.injection(lam) + jnp.einsum("wi,wij->wj", t, phi,
+                                             precision=HIGHEST)
 
 
 def flow_conservation(problem: Problem, state, *, warn: float = 1e-3,

@@ -1,11 +1,9 @@
 """Distributed-optimization collectives: compression + explicit ring AR.
 
 * ``shard_map_compat`` — the one ``jax.shard_map`` entry point of this
-  repo, papering over the 0.4.x → current API drift (experimental vs
-  public namespace, ``check_rep`` → ``check_vma`` rename).  The solver
-  core's fleet engine (``core/batch.run_batch_sharded``) and the ring
-  all-reduce below both go through it, and the CI JAX matrix keeps it
-  honest on both ends of the supported range.
+  repo, with replication checking off.  The solver core's fleet engine
+  (``core/batch.run_batch_sharded``) and the ring all-reduce below both
+  go through it.
 * ``quantize_dequantize_int8`` — symmetric per-tensor int8 gradient
   compression.  Hooked in before pjit's gradient reduction it cuts the
   cross-pod all-reduce payload 2× vs bf16 / 4× vs f32 (§Perf iteration 3
@@ -25,27 +23,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:                                  # newer jax: public API
-    from jax import shard_map as _shard_map
-except ImportError:                   # jax 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-# the replication-check kwarg was renamed check_rep -> check_vma in a
-# different release than the public export, so feature-detect it
-import inspect as _inspect
-
-_SM_KW = {("check_vma" if "check_vma" in
-           _inspect.signature(_shard_map).parameters else "check_rep"): False}
-
 
 def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across the supported JAX range (see module doc).
+    """``jax.shard_map`` with replication checking disabled.
 
-    Replication checking is disabled: the fleet engine's per-shard solves
-    are embarrassingly parallel (no cross-shard collectives), which the
-    0.4.x checker cannot always prove through a scanned solver body.
+    The fleet engine's per-shard solves are embarrassingly parallel (no
+    cross-shard collectives), which the checker cannot always prove
+    through a scanned solver body.
     """
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **_SM_KW)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def quantize_int8(x):

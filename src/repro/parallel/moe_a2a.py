@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .collectives import _SM_KW, _shard_map
+from .collectives import shard_map_compat
 
 
 def moe_a2a_apply(mesh, params, x, *, capacity_factor: float = 1.5):
@@ -78,11 +78,11 @@ def moe_a2a_apply(mesh, params, x, *, capacity_factor: float = 1.5):
         yt = yt * gval[:, None].astype(x_loc.dtype)
         return yt.reshape(b, S, D)
 
-    fn = _shard_map(
-        body, mesh=mesh,
+    fn = shard_map_compat(
+        body, mesh,
         in_specs=(P("data", None, None), P(None, None),
                   P("model", None, None), P("model", None, None)),
-        out_specs=P("data", None, None), **_SM_KW)
+        out_specs=P("data", None, None))
     return fn(x, params["router"], params["wi"], params["wo"])
 
 

@@ -344,7 +344,9 @@ def _reexec_with_forced_devices() -> int:
     import sys
 
     flags = (os.environ.get("XLA_FLAGS", "") + " " + FORCED_DEVICE_FLAG)
-    env = dict(os.environ, XLA_FLAGS=flags.strip())
+    # the child lowers for a fake host mesh only; pinned to the CPU it
+    # never reaches for a chip that the invoking process may hold
+    env = dict(os.environ, XLA_FLAGS=flags.strip(), JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-m", "repro.roofline.extract",
                         *sys.argv[1:]], env=env)
     return r.returncode
@@ -387,6 +389,7 @@ def main() -> int:
     mesh = make_production_mesh(multi_pod=False)
     archs = (args.arch,) if args.arch else ARCH_IDS
     shapes = (args.shape,) if args.shape else tuple(SHAPES)
+    failed = []
     for a in archs:
         for s in shapes:
             try:
@@ -400,6 +403,11 @@ def main() -> int:
                     print(f"[{a} × {s}] skipped", flush=True)
             except Exception as e:  # noqa: BLE001
                 print(f"[{a} × {s}] FAILED: {e!r}", flush=True)
+                failed.append(f"{a} × {s}")
+    if failed:
+        print(f"{len(failed)} cell(s) failed: {', '.join(failed)}",
+              flush=True)
+        return 1
     return 0
 
 

@@ -5,7 +5,7 @@ The contracts of DESIGN.md §16.1–16.2:
 * ``fixed_point_solve``'s implicit-function-theorem VJP matches central
   finite differences to ≤1e-4 through the full oracle (``oracle_observe``
   → ``solve_routing_implicit``), dense AND sparse.  The comparison runs
-  in float64 (``jax.experimental.enable_x64``): in f32 the FD reference
+  in float64 (``jax.enable_x64``): in f32 the FD reference
   itself carries ~1e-3 of roundoff, which would swamp the bar.
 * the layer composes with jit and vmap (the learned solver path wraps it
   in both).
@@ -81,14 +81,14 @@ def _fd_vs_implicit(graph, phi_init):
 
 
 def test_implicit_vjp_matches_fd_dense():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         g = _f64(_graph())
         err = _fd_vs_implicit(g, _f64(g.uniform_phi()))
     assert err <= 1e-4, err
 
 
 def test_implicit_vjp_matches_fd_sparse():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         gs = _f64(sparsify(_graph()))
         err = _fd_vs_implicit(gs, _f64(gs.uniform_phi()))
     assert err <= 1e-4, err

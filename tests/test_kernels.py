@@ -58,7 +58,7 @@ def test_flow_step_matches_ref(W, N, dtype):
     t = jnp.abs(_rand(ks[0], (W, N), dtype))
     phi = jnp.abs(_rand(ks[1], (W, N, N), dtype))
     inj = jnp.abs(_rand(ks[2], (W, N), dtype))
-    got = flow_step_op(t, phi, inj)
+    got = flow_step_op(t, phi, inj, interpret=True)
     want = ref.flow_step_ref(t, phi, inj)
     tol = 5e-2 if dtype == jnp.bfloat16 else 1e-4
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -75,7 +75,7 @@ def test_omd_update_matches_ref(W, N, eta):
     s = raw.sum(-1, keepdims=True)
     phi = jnp.where(s > 0, raw / jnp.where(s > 0, s, 1), 0.0)
     delta = jnp.abs(_rand(ks[2], (W, N, N), jnp.float32)) * 5
-    got = omd_update_op(phi, delta, mask, eta)
+    got = omd_update_op(phi, delta, mask, eta, interpret=True)
     want = ref.omd_update_ref(phi, delta, mask, eta)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -98,7 +98,7 @@ def test_omd_kernel_agrees_with_core_routing_step(er25_cec):
     _, t, F = cost_and_state(g, cost, phi, lam)
     delta, _ = marginals(g, cost, phi, t, F)
     want = omd_step(g, cost, phi, lam, 1.0).phi
-    got = omd_update_op(phi, delta, g.out_mask, 1.0)
+    got = omd_update_op(phi, delta, g.out_mask, 1.0, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)
 
@@ -112,7 +112,7 @@ def test_flow_kernel_agrees_with_core_propagate(er25_cec):
     inject = g.injection(lam)
     t = inject
     for _ in range(g.depth_max):
-        t = flow_step_op(t, phi, inject)
+        t = flow_step_op(t, phi, inject, interpret=True)
     want = propagate(g, phi, lam)
     np.testing.assert_allclose(np.asarray(t), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
@@ -129,7 +129,8 @@ def test_flow_step_sparse_matches_ref(W, N, D, Din):
     in_src = jnp.asarray(rng.integers(0, N, (N, Din)), jnp.int32)
     in_slot = jnp.asarray(rng.integers(0, D, (N, Din)), jnp.int32)
     in_mask = jnp.asarray(rng.random((N, Din)) > 0.4, jnp.float32)
-    got = flow_step_sparse_op(t, rows, base, in_src, in_slot, in_mask)
+    got = flow_step_sparse_op(t, rows, base, in_src, in_slot, in_mask,
+                              interpret=True)
     want = ref.flow_step_sparse_ref(t, rows, base, in_src, in_slot, in_mask)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -145,7 +146,7 @@ def test_omd_update_sparse_matches_ref(W, R, C, eta):
     s = raw.sum(-1, keepdims=True)
     phi = jnp.where(s > 0, raw / jnp.where(s > 0, s, 1), 0.0)
     delta = jnp.abs(_rand(ks[2], (W, R, C), jnp.float32)) * 5
-    got = omd_update_sparse_op(phi, delta, mask, eta)
+    got = omd_update_sparse_op(phi, delta, mask, eta, interpret=True)
     want = ref.omd_update_sparse_ref(phi, delta, mask, eta)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -168,13 +169,14 @@ def test_sparse_kernels_agree_with_core_sparse_step(er25_cec):
     base = sp.source_inflow(gs, phi, lam)
     t0 = gs.injection(lam)
     got = flow_step_sparse_op(t0, phi.rows, base, gs.in_src, gs.in_slot,
-                              gs.in_mask)
+                              gs.in_mask, interpret=True)
     want = base + sp._relay_inflow(gs, phi.rows, t0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
     _, t, F = cost_and_state(gs, cost, phi, lam)
     delta, _ = marginals(gs, cost, phi, t, F)
-    upd = omd_update_sparse_op(phi.rows, delta.rows, gs.out_mask, 1.0)
+    upd = omd_update_sparse_op(phi.rows, delta.rows, gs.out_mask, 1.0,
+                               interpret=True)
     want_upd = sp.eg_update(phi.rows, delta.rows, gs.out_mask, 1.0)
     np.testing.assert_allclose(np.asarray(upd), np.asarray(want_upd),
                                rtol=1e-5, atol=1e-5)
@@ -192,7 +194,7 @@ def test_mamba_scan_matches_ref(B, S, di, ds, dtype):
     A = -jnp.abs(_rand(ks[2], (di, ds), jnp.float32))
     Bm = _rand(ks[3], (B, S, ds), dtype)
     Cm = _rand(ks[4], (B, S, ds), dtype)
-    got = mamba_scan_op(u, dt, A, Bm, Cm)
+    got = mamba_scan_op(u, dt, A, Bm, Cm, interpret=True)
     want = ref.mamba_scan_ref(u, dt, A, Bm, Cm)
     tol = 5e-2 if dtype == jnp.bfloat16 else 1e-4
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -213,6 +215,6 @@ def test_mamba_kernel_matches_model_layer_scan():
     Bm = _rand(ks[3], (B, S, ds), jnp.float32)
     Cm = _rand(ks[4], (B, S, ds), jnp.float32)
     want, _ = _mamba_scan(u, dt, A, Bm, Cm, None)
-    got = mamba_scan_op(u, dt, A, Bm, Cm)
+    got = mamba_scan_op(u, dt, A, Bm, Cm, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)

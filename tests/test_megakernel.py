@@ -244,6 +244,18 @@ def test_policy_engages_under_override_and_respects_vmem():
     assert not dispatch.use_megakernel(16, 3)
 
 
+def test_sparse_megakernel_never_picked_on_tpu(monkeypatch):
+    """The sparse kernel's gathers do not lower for a TPU: there the
+    default dispatch leaves a sparse graph to the stitched kernels, and
+    forcing the megakernel on it is an error, not a quiet fallback."""
+    monkeypatch.setattr(dispatch.jax, "default_backend", lambda: "tpu")
+    assert dispatch.use_megakernel(300, 3)
+    assert not dispatch.use_megakernel(300, 3, sparse=True)
+    with dispatch.megakernel_dispatch(1):
+        with pytest.raises(NotImplementedError, match="sparse megakernel"):
+            dispatch.use_megakernel(300, 3, sparse=True)
+
+
 def test_bf16_doubles_admissible_size():
     """§17.3: halving the φ itemsize roughly doubles what fits."""
     n = 1024

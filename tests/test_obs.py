@@ -243,8 +243,7 @@ def test_regret_monitor_agrees_with_genie_accounting():
     comp = np.zeros_like(traj)
     for j, seg in enumerate(res.segments):
         comp[seg.start:seg.start + seg.n_iters] = genie[0, j]
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         tel = _ring_from_utilities(jnp.asarray(traj, jnp.float64))
         got = float(dynamic_regret(tel, jnp.asarray(comp)).value)
     assert abs(got - expected) <= 1e-6 * max(1.0, abs(expected))

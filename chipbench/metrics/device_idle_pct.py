@@ -1,0 +1,8 @@
+"""Device: the share of the traced window in which no operation ran."""
+
+
+def read(ctx: dict):
+    tr = ctx["trace"]
+    if tr is None:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])

@@ -14,9 +14,11 @@ signals instead of after-the-fact test assertions:
 * :mod:`repro.obs.monitors` — paper-derived invariant monitors as pure
   functions over the ring and the live iterates, each with warn/trip
   thresholds and a fleet-vmapped batch form.
-* :mod:`repro.obs.trace` — host-side Chrome-trace (trace-event JSON)
-  timelines of control intervals, scenario segments and kernel-dispatch
-  decisions.
+* :mod:`repro.obs.trace` — host spans on the profiler's clock:
+  Chrome-trace (trace-event JSON) timelines of control intervals,
+  scenario segments and kernel-dispatch decisions, per-phase aggregates
+  of each ``control_step`` and the ``host_syncs`` counter of
+  :func:`~repro.obs.trace.to_host`.
 * :mod:`repro.obs.export` — host-side ring export + JSON-lines metrics
   aligned with the perf-trajectory schema rows.
 
@@ -29,8 +31,8 @@ from __future__ import annotations
 import importlib
 
 from .telemetry import Telemetry, Verdict, annotate, init_ring, record
-from .trace import (Tracer, current_tracer, install_tracer, instant, span,
-                    uninstall_tracer)
+from .trace import (Tracer, current_tracer, install_tracer, instant, phase,
+                    span, to_host, uninstall_tracer)
 
 _LAZY = {
     # monitors / export pull repro.core — resolve on first access so that
@@ -50,7 +52,7 @@ _LAZY_NAMES = {
 __all__ = [
     "Telemetry", "Verdict", "init_ring", "record", "annotate",
     "Tracer", "install_tracer", "uninstall_tracer", "current_tracer",
-    "span", "instant",
+    "span", "instant", "phase", "to_host",
     *sorted(_LAZY), *sorted(_LAZY_NAMES),
 ]
 

@@ -77,7 +77,11 @@ class Telemetry:
         Oracle invocations this interval (2W+1 sampled/megakernel, 2
         learned).
     ``wall_clock_us [C]``
-        Host-measured solver wall-clock in µs.  NaN until annotated.
+        Host time in µs from the interval's start to the step's result
+        on the host: the clock stops after the first read of the new Λ,
+        which waits for the step, so it holds the perturbation sweep,
+        its measurement, the dispatch and the step's device time.  NaN
+        until annotated.
 
     ``head`` is the *next* write slot (monotone int32, slot = head mod C);
     ``count`` saturates at C — together they define the valid window and

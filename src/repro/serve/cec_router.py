@@ -64,6 +64,7 @@ from repro.core.scenario import (DemandShift, Event, ScenarioState,
                                  apply_event)
 from repro.core.solver import SolverConfig, SolverState, project_box_simplex
 from repro.core.utility import OnlineFitter
+from repro.obs import trace as _obs_trace
 
 GRAD_POLICIES = ("sampled", "learned", "auto")
 
@@ -220,67 +221,79 @@ class CECRouter:
         layer (DESIGN.md §16.4).  Everything else — oracle invocations,
         gradient, mirror ascent, exact projection, committed observation
         — is a single jitted ``solver.fused_step`` call; the
-        ``SolverState`` never leaves the device.
+        ``SolverState`` never leaves the device.  Under an installed
+        tracer the interval is one ``router.interval`` span over the
+        ``control.*`` phases it shares with ``RouterFleet``, and its four
+        device-to-host reads (three learned) go through
+        ``obs.trace.to_host`` (DESIGN.md §18.3).
         """
-        from repro.obs import trace as _obs_trace
-
         mode = self._grad_mode_now()
         W = self.graph.n_sessions
+        phase, to_host = _obs_trace.phase, _obs_trace.to_host
         with _obs_trace.span("router.interval", cat="interval",
                              args={"t": len(self.history), "mode": mode}):
             t0 = time.perf_counter()
             if mode == "learned":
                 self._migrated = True
-                prob = self.problem.with_utilities(self.util_family,
-                                                   self.fitter.params)
-                cfg = self.config.replace(grad_mode="learned")
-                fused = _solver.fused_step(cfg)
-                if self.tel is None:
-                    self.state, info = fused(
-                        prob, self.state, jnp.zeros((2 * W,), jnp.float32))
-                else:
-                    self.state, info, self.tel = fused(
-                        prob, self.state, jnp.zeros((2 * W,), jnp.float32),
-                        self.tel)
+                with phase("control.dispatch"):
+                    prob = self.problem.with_utilities(self.util_family,
+                                                       self.fitter.params)
+                    cfg = self.config.replace(grad_mode="learned")
+                    fused = _solver.fused_step(cfg)
+                    zeros = jnp.zeros((2 * W,), jnp.float32)
+                    if self.tel is None:
+                        self.state, info = fused(prob, self.state, zeros)
+                    else:
+                        self.state, info, self.tel = fused(
+                            prob, self.state, zeros, self.tel)
                 oracle_calls = 1
             else:
-                pert = _solver.perturbed_allocations(self.state.lam,
-                                                     self.config.delta)
-                task_u = jnp.asarray(
-                    _call_utility(utility_fn, np.asarray(pert)))
-                fused = _solver.fused_step(self.config)
-                if self.tel is None:
-                    self.state, info = fused(self.problem, self.state,
-                                             task_u)
-                else:
-                    self.state, info, self.tel = fused(
-                        self.problem, self.state, task_u, self.tel)
+                with phase("control.perturb"):
+                    pert = to_host(_solver.perturbed_allocations(
+                        self.state.lam, self.config.delta))
+                with phase("control.measure"):
+                    task_u = _call_utility(utility_fn, pert)
+                with phase("control.dispatch"):
+                    fused = _solver.fused_step(self.config)
+                    if self.tel is None:
+                        self.state, info = fused(self.problem, self.state,
+                                                 jnp.asarray(task_u))
+                    else:
+                        self.state, info, self.tel = fused(
+                            self.problem, self.state, jnp.asarray(task_u),
+                            self.tel)
                 if self.fitter is not None:
-                    self.fitter.add(np.asarray(pert), np.asarray(task_u))
+                    with phase("control.fit"):
+                        self.fitter.add(pert, task_u)
                 oracle_calls = 2 * W + 1
+            # the step's fresh Λ on the host: the first read that waits for
+            # the step, so the clock stops once the solver's result exists
+            lam = to_host(self.state.lam)
             solver_us = (time.perf_counter() - t0) * 1e6
-            u_task = float(
-                _call_utility(utility_fn,
-                              np.asarray(self.state.lam)[None])[0])
+            with phase("control.measure"):
+                u_task = float(_call_utility(utility_fn, lam[None])[0])
             if self.fitter is not None:
-                self.fitter.observe_live(np.asarray(self.state.lam), u_task)
-                self.fitter.maybe_fit()
-            rec = {"lam": np.asarray(self.state.lam).copy(),
-                   "cost": float(info.cost),
-                   "utility": u_task - float(info.cost),
-                   "grad": np.asarray(info.grad).copy(),
-                   "mode": mode,
-                   "oracle_calls": oracle_calls}
-            if self.tel is not None:
-                # patch the row the jitted step NaN-seeded: the measured
-                # net utility and the host-observed solver wall-clock
-                # (dispatch-inclusive — the control loop's real budget)
-                from repro.obs import telemetry as _obs_tel
+                with phase("control.fit"):
+                    self.fitter.observe_live(lam, u_task)
+                    self.fitter.maybe_fit()
+            with phase("control.record"):
+                cost = float(to_host(info.cost))
+                rec = {"lam": lam,
+                       "cost": cost,
+                       "utility": u_task - cost,
+                       "grad": to_host(info.grad),
+                       "mode": mode,
+                       "oracle_calls": oracle_calls}
+                if self.tel is not None:
+                    # patch the row the jitted step NaN-seeded: the
+                    # measured net utility and the host time from the
+                    # interval's start to the step's result on the host
+                    from repro.obs import telemetry as _obs_tel
 
-                self.tel = _obs_tel.annotate_donated(
-                    self.tel, utility=jnp.float32(rec["utility"]),
-                    wall_clock_us=jnp.float32(solver_us))
-            self.history.append(rec)
+                    self.tel = _obs_tel.annotate_donated(
+                        self.tel, utility=jnp.float32(rec["utility"]),
+                        wall_clock_us=jnp.float32(solver_us))
+                self.history.append(rec)
         return rec
 
     def verdicts(self, comparator=None) -> dict:
@@ -374,8 +387,6 @@ class CECRouter:
         does.  Returns the post-event state — thread it into the next
         call.  Bank swaps change only the *measured* utility (the
         environment), so the router's iterates carry over untouched."""
-        from repro.obs import trace as _obs_trace
-
         _obs_trace.instant(f"event:{event.kind}", cat="scenario",
                            args={"kind": event.kind,
                                  "at": len(self.history)})

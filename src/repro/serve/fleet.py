@@ -59,6 +59,7 @@ from repro.core.scenario import (DemandShift, Event, ScenarioState,
                                  apply_event)
 from repro.core.solver import SolverConfig, SolverState, project_box_simplex
 from repro.core.utility import OnlineFitter
+from repro.obs import trace as _obs_trace
 
 from .cec_router import GRAD_POLICIES, _call_utility
 
@@ -247,17 +248,19 @@ class RouterFleet:
         return self._view
 
     def _publish(self):
-        graph = self.batch.stacked_graph()
-        if self.tel is None:
-            lam, weights = _publisher(_dispatch_key())(graph, self.state)
-            self._view = FleetView(lam=lam, weights=weights)
-        else:
-            lam, weights, verdicts = _publisher(
-                _dispatch_key(), self.cost_name)(
-                    graph, self.state, jnp.asarray(self.lam_totals),
-                    self.tel)
-            self._view = FleetView(lam=lam, weights=weights,
-                                   verdicts=verdicts)
+        with _obs_trace.phase("control.publish"):
+            graph = self.batch.stacked_graph()
+            if self.tel is None:
+                lam, weights = _publisher(_dispatch_key())(graph,
+                                                           self.state)
+                self._view = FleetView(lam=lam, weights=weights)
+            else:
+                lam, weights, verdicts = _publisher(
+                    _dispatch_key(), self.cost_name)(
+                        graph, self.state, jnp.asarray(self.lam_totals),
+                        self.tel)
+                self._view = FleetView(lam=lam, weights=weights,
+                                       verdicts=verdicts)
 
     # -- measured utilities -------------------------------------------------
     def _measure(self, utility_fn, lams: np.ndarray) -> np.ndarray:
@@ -304,84 +307,102 @@ class RouterFleet:
         measurement per tenant per interval, stacked [K, W, P] surrogate
         params threaded through ``fused_step_batch`` as a data leaf
         (refits never retrace; DESIGN.md §16.4).
-        """
-        from repro.obs import trace as _obs_trace
 
+        Under an installed tracer the interval is one ``fleet.interval``
+        span, its steps are the ``control.*`` phases it shares with
+        ``CECRouter``, and every device-to-host read goes through
+        ``obs.trace.to_host``: five in a sampled interval without
+        fitters (DESIGN.md §18.3).
+        """
         mode = self._grad_mode_now()
         K, W = self.n_tenants, self.n_sessions
+        phase, to_host = _obs_trace.phase, _obs_trace.to_host
         with _obs_trace.span("fleet.interval", cat="interval",
                              args={"t": len(self.history), "mode": mode,
                                    "tenants": K}):
             t0 = time.perf_counter()
             if mode == "learned":
                 self._migrated = True
-                params = jnp.stack([f.params for f in self.fitters])
-                step = fused_step_batch(
-                    self.config.replace(grad_mode="learned"),
-                    cost=self.cost_name, donate=self.donate,
-                    util_family=self.util_family)
-                zeros = jnp.zeros((K, 2 * W), jnp.float32)
-                if self.tel is None:
-                    self.state, info = step(
-                        self.batch.stacked_graph(),
-                        jnp.asarray(self.lam_totals), self.state, zeros,
-                        params)
-                else:
-                    self.state, info, self.tel = step(
-                        self.batch.stacked_graph(),
-                        jnp.asarray(self.lam_totals), self.state, zeros,
-                        self.tel, params)
+                with phase("control.dispatch"):
+                    params = jnp.stack([f.params for f in self.fitters])
+                    step = fused_step_batch(
+                        self.config.replace(grad_mode="learned"),
+                        cost=self.cost_name, donate=self.donate,
+                        util_family=self.util_family)
+                    zeros = jnp.zeros((K, 2 * W), jnp.float32)
+                    if self.tel is None:
+                        self.state, info = step(
+                            self.batch.stacked_graph(),
+                            jnp.asarray(self.lam_totals), self.state, zeros,
+                            params)
+                    else:
+                        self.state, info, self.tel = step(
+                            self.batch.stacked_graph(),
+                            jnp.asarray(self.lam_totals), self.state, zeros,
+                            self.tel, params)
                 oracle_calls = 1
             else:
                 delta = self.config.delta
-                pert = jax.vmap(lambda l: _solver.perturbed_allocations(
-                    l, delta))(self._view.lam)
-                pert = np.asarray(pert)
-                task_u = self._measure(utility_fn, pert)
-                step = fused_step_batch(self.config, cost=self.cost_name,
-                                        donate=self.donate)
-                if self.tel is None:
-                    self.state, info = step(
-                        self.batch.stacked_graph(),
-                        jnp.asarray(self.lam_totals),
-                        self.state, jnp.asarray(task_u))
-                else:
-                    self.state, info, self.tel = step(
-                        self.batch.stacked_graph(),
-                        jnp.asarray(self.lam_totals),
-                        self.state, jnp.asarray(task_u), self.tel)
+                with phase("control.perturb"):
+                    pert = to_host(jax.vmap(
+                        lambda l: _solver.perturbed_allocations(l, delta))(
+                            self._view.lam))
+                with phase("control.measure"):
+                    task_u = self._measure(utility_fn, pert)
+                with phase("control.dispatch"):
+                    step = fused_step_batch(self.config, cost=self.cost_name,
+                                            donate=self.donate)
+                    if self.tel is None:
+                        self.state, info = step(
+                            self.batch.stacked_graph(),
+                            jnp.asarray(self.lam_totals),
+                            self.state, jnp.asarray(task_u))
+                    else:
+                        self.state, info, self.tel = step(
+                            self.batch.stacked_graph(),
+                            jnp.asarray(self.lam_totals),
+                            self.state, jnp.asarray(task_u), self.tel)
                 if self.fitters is not None:
-                    for k, f in enumerate(self.fitters):
-                        f.add(pert[k], task_u[k])
+                    with phase("control.fit"):
+                        for k, f in enumerate(self.fitters):
+                            f.add(pert[k], task_u[k])
                 oracle_calls = 2 * W + 1
+            # the step's fresh Λ on the host: the first read that waits for
+            # the step, so the clock stops once the solver's result exists
+            lam_new = to_host(self.state.lam)
             solver_us = (time.perf_counter() - t0) * 1e6
-            # measure at the committed Λ (the step's fresh output — value-
-            # identical to the view published below, which happens after
-            # the ring annotation so the verdicts see this interval's U)
-            u_task = self._measure(
-                utility_fn, np.asarray(self.state.lam)[:, None, :])[:, 0]
+            # measure at the committed Λ (value-identical to the view
+            # published below, which happens after the ring annotation so
+            # the verdicts see this interval's U)
+            with phase("control.measure"):
+                u_task = self._measure(utility_fn,
+                                       lam_new[:, None, :])[:, 0]
             if self.fitters is not None:
-                lam = np.asarray(self._view.lam)
-                for k, f in enumerate(self.fitters):
-                    f.observe_live(lam[k], float(u_task[k]))
-                    f.maybe_fit()
-            cost = np.asarray(info.cost, np.float32)
+                lam = to_host(self._view.lam)
+                with phase("control.fit"):
+                    for k, f in enumerate(self.fitters):
+                        f.observe_live(lam[k], float(u_task[k]))
+                        f.maybe_fit()
+            cost = to_host(info.cost, np.float32)
             if self.tel is not None:
                 # per-lane net utility; one fused call serves all K
                 # lanes, so they share the measured wall-clock
                 from repro.obs import telemetry as _obs_tel
 
-                self.tel = _obs_tel.annotate_donated(
-                    self.tel, utility=jnp.asarray(u_task - cost),
-                    wall_clock_us=jnp.full((K,), solver_us, jnp.float32))
+                with phase("control.record"):
+                    self.tel = _obs_tel.annotate_donated(
+                        self.tel, utility=jnp.asarray(u_task - cost),
+                        wall_clock_us=jnp.full((K,), solver_us,
+                                               jnp.float32))
             self._publish()
-            rec = {"lam": np.asarray(self._view.lam).copy(),
-                   "cost": cost,
-                   "utility": u_task - cost,
-                   "grad": np.asarray(info.grad).copy(),
-                   "mode": mode,
-                   "oracle_calls": oracle_calls}
-            self.history.append(rec)
+            with phase("control.record"):
+                rec = {"lam": to_host(self._view.lam),
+                       "cost": cost,
+                       "utility": u_task - cost,
+                       "grad": to_host(info.grad),
+                       "mode": mode,
+                       "oracle_calls": oracle_calls}
+                self.history.append(rec)
         return rec
 
     # -- churn --------------------------------------------------------------

@@ -42,6 +42,7 @@ from typing import Any, Literal, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import dispatch
 from .graph import CECGraphSparse, SparsePhi
@@ -255,6 +256,31 @@ def perturbed_allocations(lam: Array, delta: float) -> Array:
     """
     signs, dirs = _perturbation_basis(lam.shape[-1])
     return lam + signs[:, None] * delta * dirs
+
+
+@functools.lru_cache(maxsize=64)
+def _perturbation_offsets(W: int, delta: float) -> np.ndarray:
+    """[2W, W] float32 offsets sign·δ·e_w in :func:`_perturbation_basis`
+    order, computed as :func:`perturbed_allocations` computes them
+    (read-only; cached per ``(W, δ)``)."""
+    signs = np.tile(np.asarray([1.0, -1.0], np.float32), W)
+    dirs = np.repeat(np.eye(W, dtype=np.float32), 2, axis=0)
+    offsets = signs[:, None] * np.float32(delta) * dirs
+    offsets.flags.writeable = False
+    return offsets
+
+
+def perturbed_allocations_host(lam: np.ndarray, delta: float) -> np.ndarray:
+    """:func:`perturbed_allocations` with numpy, on a host copy of Λ:
+    [..., W] -> [..., 2W, W] float32, the same rows bit for bit.
+
+    For a serving control interval, which holds Λ on the host anyway:
+    one float32 add per entry, no trace and no device dispatch (the
+    offsets are exact in float32, so the device path also rounds once).
+    """
+    lam = np.asarray(lam, np.float32)
+    return lam[..., None, :] + _perturbation_offsets(lam.shape[-1],
+                                                     float(delta))
 
 
 # ---------------------------------------------------------------------------

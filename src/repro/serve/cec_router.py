@@ -249,8 +249,8 @@ class CECRouter:
                 oracle_calls = 1
             else:
                 with phase("control.perturb"):
-                    pert = to_host(_solver.perturbed_allocations(
-                        self.state.lam, self.config.delta))
+                    pert = _solver.perturbed_allocations_host(
+                        to_host(self.state.lam), self.config.delta)
                 with phase("control.measure"):
                     task_u = _call_utility(utility_fn, pert)
                 with phase("control.dispatch"):

@@ -342,11 +342,9 @@ class RouterFleet:
                             self.tel, params)
                 oracle_calls = 1
             else:
-                delta = self.config.delta
                 with phase("control.perturb"):
-                    pert = to_host(jax.vmap(
-                        lambda l: _solver.perturbed_allocations(l, delta))(
-                            self._view.lam))
+                    pert = _solver.perturbed_allocations_host(
+                        to_host(self._view.lam), self.config.delta)
                 with phase("control.measure"):
                     task_u = self._measure(utility_fn, pert)
                 with phase("control.dispatch"):

@@ -94,10 +94,17 @@ def test_phase_counts_and_host_syncs_per_interval(tracer, kind, policy):
     jax.clear_caches()              # the first interval compiles the step
     entry = _entry(kind, policy)
     before = tracer.snapshot(restart_longest=True)
-    for _ in range(3):
+    entry.control_step(_utility)
+    warm = tracer.snapshot()
+    for _ in range(2):
         entry.control_step(_utility)
     assert [r["mode"] for r in entry.history] == ["sampled"] * 3
     got = obs_trace.delta(before, tracer.snapshot())
+    # once the first interval has compiled, the sweep is built on the
+    # host from one read of Λ: nothing traces under control.perturb
+    steady = obs_trace.delta(warm, tracer.snapshot())
+    assert steady["phases"]["control.perturb"]["count"] == 2
+    assert steady["phases"]["control.perturb"]["traces"] == 0
     assert got["host_syncs"] == 3 * syncs
     assert {n: p["count"] for n, p in got["phases"].items()} == \
         {n: 3 * c for n, c in counts.items()}

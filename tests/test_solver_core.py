@@ -322,3 +322,29 @@ def test_paper_preset_module():
     problem = cec_paper.build_problem()
     assert problem.n_sessions == 3
     assert float(np.asarray(problem.lam_total)) == 60.0
+
+
+@pytest.mark.parametrize("lead", [(), (5,)], ids=["one", "K"])
+@pytest.mark.parametrize("delta", [0.5, 0.1, 1e-3])
+@pytest.mark.parametrize("W", [1, 2, 3, 8])
+def test_perturbed_allocations_host_matches_device_bits(W, delta, lead):
+    """The host sweep the serving entries build equals the eager
+    (vmapped) ``perturbed_allocations`` bit for bit, float32, in the
+    observation order: row 2w = Λ + δ·e_w, row 2w+1 = Λ − δ·e_w."""
+    rng = np.random.default_rng(W)
+    lam = rng.uniform(0.0, 60.0, lead + (W,)).astype(np.float32)
+    lam.reshape(-1)[0] = 0.0                 # a tenant at its box edge
+
+    def dev(lam):
+        return S.perturbed_allocations(lam, delta)
+
+    want = np.asarray((jax.vmap(dev) if lead else dev)(jnp.asarray(lam)))
+    got = S.perturbed_allocations_host(lam, delta)
+    assert got.dtype == np.float32 and got.shape == lead + (2 * W, W)
+    np.testing.assert_array_equal(got.view(np.uint32),
+                                  want.view(np.uint32))
+    for w in range(W):
+        e = np.zeros(W, np.float32)
+        e[w] = np.float32(delta)
+        np.testing.assert_array_equal(got[..., 2 * w, :], lam + e)
+        np.testing.assert_array_equal(got[..., 2 * w + 1, :], lam - e)

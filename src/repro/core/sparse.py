@@ -127,20 +127,24 @@ def propagate(graph: CECGraphSparse, phi: SparsePhi, lam: Array) -> Array:
     ``t' = base + relay_gather(t)`` with the W sink entries overlaid from
     :func:`_sink_inflow` (old ``t``, Jacobi semantics).  Size-dispatched
     like the dense path: past ``dispatch.use_kernels(n_bar)`` the gather
-    step runs the Pallas ``flow_step_sparse`` kernel.
+    step runs the Pallas ``flow_step_sparse`` kernel, on in-edge shares
+    gathered once for all the steps.
     """
     inject = graph.injection(lam)
     base = source_inflow(graph, phi, lam)
     wi, sinks = jnp.arange(graph.n_sessions), graph.sinks
 
     if dispatch.use_kernels(graph.n_bar):
-        from repro.kernels.ops import flow_step_sparse_op
+        from repro.kernels.ops import flow_in_edges_sparse, flow_step_sparse_op
 
         interpret = dispatch.kernel_interpret()
+        # φ holds over the relaxation: its in-edge shares are gathered and
+        # laid out once, not in every step
+        in_edges = flow_in_edges_sparse(phi.rows, graph.in_src,
+                                        graph.in_slot, graph.in_mask)
 
         def relay(t):
-            return flow_step_sparse_op(t, phi.rows, base, graph.in_src,
-                                       graph.in_slot, graph.in_mask,
+            return flow_step_sparse_op(t, base, in_edges,
                                        interpret=interpret)
     else:
         def relay(t):

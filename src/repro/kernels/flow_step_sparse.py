@@ -8,8 +8,9 @@ each output node j accumulates its padded in-edge segment
 — a gather + masked column reduction, O(E) work per step.  ``pv`` holds
 the routing share φ[w, in_src[j, d], in_slot[j, d]] of each in-edge,
 masked.  Its index is two-dimensional, which no TPU kernel gathers, so
-``kernels.ops.flow_step_sparse_op`` gathers it in XLA and the kernel
-gathers the rate vector t.  ``base`` is the precomputed constant inflow
+``kernels.ops.flow_in_edges_sparse`` gathers it in XLA, once for all the
+steps of a relaxation (φ holds over them), and the kernel gathers the
+rate vector t.  ``base`` is the precomputed constant inflow
 (exogenous injection + the virtual source's admission flow,
 ``core.sparse.source_inflow``); the W virtual-sink entries are overlaid
 by the caller from the analytic compute-edge reduction, so no hub row
@@ -22,8 +23,8 @@ that chunk.  That is (N/128)² vreg-sized gathers per session and step.
 The in-lists are laid out [Din, N] (nodes on lanes) so the reduction
 over in-slots runs along sublanes.  Dispatched by
 ``core.sparse.propagate`` when ``dispatch.use_kernels(n_bar)`` holds,
-through ``kernels.ops.flow_step_sparse_op``, which does that layout and
-pads nodes to 128 and in-slots to 8.
+through ``kernels.ops.flow_step_sparse_op`` on the layout
+``flow_in_edges_sparse`` makes (nodes padded to 128, in-slots to 8).
 """
 from __future__ import annotations
 
@@ -86,4 +87,5 @@ def flow_step_sparse(t, pv, base, lane, chunk, *, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((W, 1, N), t.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BYTES),
         interpret=interpret,
+        name="edge_flow_step",      # a device trace shows edge_flow_step.N
     )(t, pv, base, lane, chunk)

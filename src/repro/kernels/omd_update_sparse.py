@@ -54,4 +54,5 @@ def omd_update_sparse(phi, delta, mask, eta: float, *, br: int = 128,
         out_shape=jax.ShapeDtypeStruct(phi.shape, phi.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BYTES),
         interpret=interpret,
+        name="edge_omd_update",     # a device trace shows edge_omd_update.N
     )(phi, delta, mask)

@@ -95,24 +95,36 @@ def omd_update_op(phi, delta, mask, eta, *, interpret):
     return out[:, :N, :N]
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def flow_step_sparse_op(t, rows, base, in_src, in_slot, in_mask, *,
-                        interpret):
-    """Padded/sliced sparse relaxation step (see flow_step_sparse.py).
+@jax.jit
+def flow_in_edges_sparse(rows, in_src, in_slot, in_mask):
+    """The sparse relaxation step's in-edge operands (see
+    flow_step_sparse.py), for a routing φ that holds over many steps.
 
-    Gathers the masked in-edge routing shares pv [W, N, Din] here, in XLA,
-    then lays the in-lists out nodes-on-lanes ([Din, N], Din padded to 8,
-    N to 128) with each tail split into its lane and 128-node chunk.
-    Padded in-slots carry pv 0 and point at node 0.
+    Gathers the masked in-edge routing shares pv [W, N, Din] in XLA, then
+    lays the in-lists out nodes-on-lanes ([Din, N], Din padded to 8, N to
+    128) with each tail split into its lane and 128-node chunk.  Padded
+    in-slots carry pv 0 and point at node 0.  The gather runs under the
+    scope ``edge_pv_gather``: XLA names its fusion ``fusion.N`` all the
+    same, so only the op's metadata carries the scope.  Returns
+    (pv, lane, chunk) for :func:`flow_step_sparse_op`.
     """
-    N = t.shape[1]
-    pv = rows[:, in_src, in_slot] * in_mask                   # [W, N, Din]
+    with jax.named_scope("edge_pv_gather"):
+        pv = rows[:, in_src, in_slot] * in_mask               # [W, N, Din]
     pvt = _pad_to(_pad_to(jnp.swapaxes(pv, 1, 2), 1, 8), 2, 128)
     src = _pad_to(_pad_to(in_src.T, 0, 8), 1, 128)
+    return pvt, src % 128, src // 128
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def flow_step_sparse_op(t, base, in_edges, *, interpret):
+    """Padded/sliced sparse relaxation step (see flow_step_sparse.py) on
+    the in-edge operands :func:`flow_in_edges_sparse` lays out once for
+    all the steps of a relaxation."""
+    N = t.shape[1]
+    pv, lane, chunk = in_edges
     tp = _pad_to(t, 1, 128)[:, None, :]
     bp = _pad_to(base, 1, 128)[:, None, :]
-    out = flow_step_sparse(tp, pvt, bp, src % 128, src // 128,
-                           interpret=interpret)
+    out = flow_step_sparse(tp, pv, bp, lane, chunk, interpret=interpret)
     return out[:, 0, :N]
 
 
@@ -228,5 +240,5 @@ def mamba_scan_op(u, dt, A, Bm, Cm, *, interpret):
 
 
 __all__ = ["control_step_op", "control_step_sparse_op", "flash_attention_op",
-           "flow_step_op", "flow_step_sparse_op", "mamba_scan_op",
-           "omd_update_op", "omd_update_sparse_op", "ref"]
+           "flow_in_edges_sparse", "flow_step_op", "flow_step_sparse_op",
+           "mamba_scan_op", "omd_update_op", "omd_update_sparse_op", "ref"]

@@ -5,9 +5,9 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.ops import (flash_attention_op, flow_step_op,
-                               flow_step_sparse_op, omd_update_op,
-                               omd_update_sparse_op)
+from repro.kernels.ops import (flash_attention_op, flow_in_edges_sparse,
+                               flow_step_op, flow_step_sparse_op,
+                               omd_update_op, omd_update_sparse_op)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -129,8 +129,9 @@ def test_flow_step_sparse_matches_ref(W, N, D, Din):
     in_src = jnp.asarray(rng.integers(0, N, (N, Din)), jnp.int32)
     in_slot = jnp.asarray(rng.integers(0, D, (N, Din)), jnp.int32)
     in_mask = jnp.asarray(rng.random((N, Din)) > 0.4, jnp.float32)
-    got = flow_step_sparse_op(t, rows, base, in_src, in_slot, in_mask,
-                              interpret=True)
+    got = flow_step_sparse_op(
+        t, base, flow_in_edges_sparse(rows, in_src, in_slot, in_mask),
+        interpret=True)
     want = ref.flow_step_sparse_ref(t, rows, base, in_src, in_slot, in_mask)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
@@ -168,8 +169,9 @@ def test_sparse_kernels_agree_with_core_sparse_step(er25_cec):
     phi = gs.uniform_phi()
     base = sp.source_inflow(gs, phi, lam)
     t0 = gs.injection(lam)
-    got = flow_step_sparse_op(t0, phi.rows, base, gs.in_src, gs.in_slot,
-                              gs.in_mask, interpret=True)
+    in_edges = flow_in_edges_sparse(phi.rows, gs.in_src, gs.in_slot,
+                                    gs.in_mask)
+    got = flow_step_sparse_op(t0, base, in_edges, interpret=True)
     want = base + sp._relay_inflow(gs, phi.rows, t0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)

@@ -84,6 +84,7 @@ def _dense_step_args(n_phys, n_sessions, depth):
 def _compile(fn, args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text       # the Pallas kernel is in it
+    return text
 
 
 def _largest_admitted_n_bar(n_sessions, itemsize):
@@ -133,18 +134,25 @@ def test_stitched_dense_kernels_compile_at_dense_stitched_shape(one_chip):
 
 
 # (N, W, slots per row, source fan-out, in-slots) of the power-law graphs
-# ``topo.make_fleet("power_law", N)`` builds with W versions
+# ``topo.make_fleet("power_law", N)`` builds with W versions, and of the
+# benchmark's metro fleet (``chipbench/configs/metro-ba-w3.json``: the
+# Barabási–Albert builder at ``structure_seed`` 0, N = 3233, W = 3)
 @pytest.mark.parametrize("n_phys,n_sessions,d_max,d_src,d_in",
-                         [(1024, 3, 55, 346, 22), (4096, 16, 139, 267, 13)])
+                         [(1024, 3, 55, 346, 22), (4096, 16, 139, 267, 13),
+                          (3233, 3, 106, 1082, 54)])
 def test_stitched_sparse_kernels_compile_at_sparse_shapes(
         one_chip, n_phys, n_sessions, d_max, d_src, d_in):
+    """Each kernel's op carries its own name, which the chip's device
+    trace shows (``chipbench/metrics/edge_kernels_ms.py`` reads it)."""
     nb = n_phys + 1 + n_sessions
     vec, rows = _sds((n_sessions, nb)), _sds((n_sessions, nb, d_max))
     inl = _sds((nb, d_in), I32)
-    _compile(lambda t, r, b, s, sl, m: ops.flow_step_sparse_op(
-        t, r, b, s, sl, m, interpret=False),
+    text = _compile(lambda t, r, b, s, sl, m: ops.flow_step_sparse_op(
+        t, b, ops.flow_in_edges_sparse(r, s, sl, m), interpret=False),
         _shapes(one_chip, (vec, rows, vec, inl, inl, _sds((nb, d_in)))))
+    assert "%edge_flow_step" in text
     for part in (rows, _sds((n_sessions, d_src, 1))):
-        _compile(lambda p, d, m: ops.omd_update_sparse_op(
+        text = _compile(lambda p, d, m: ops.omd_update_sparse_op(
             p, d, m, 3.0, interpret=False),
             _shapes(one_chip, (part, part, part)))
+        assert "%edge_omd_update" in text

@@ -164,17 +164,21 @@ class CECGraphBatch:
 
 def pad_sparse_graph(graph: CECGraphSparse, n_phys: int,
                      depth_max: int | None = None, d_max: int | None = None,
-                     d_src: int | None = None,
-                     d_in_max: int | None = None) -> CECGraphSparse:
+                     d_src: int | None = None, d_in_max: int | None = None,
+                     d_light: int | None = None,
+                     n_heavy: int | None = None) -> CECGraphSparse:
     """Embed a sparse graph into a larger index/slot space.
 
     The edge-list counterpart of :func:`pad_graph`: physical nodes keep
     their indices, pad nodes are isolated, the virtual source and sinks
     relocate to the tail positions, and every slot axis grows to the
     requested width (slots keep their positions — crucial for ``in_slot``
-    validity).  Node indices stored in ``nbr``/``src_nbr`` are remapped
-    through the same relocation.  Solve-equivalent by the same argument as
-    the dense pad (extra rows/slots carry zero mask).
+    validity).  Node indices stored in ``nbr``/``src_nbr``/``heavy`` are
+    remapped through the same relocation.  Solve-equivalent by the same
+    argument as the dense pad (extra rows/slots carry zero mask).  The
+    marginal split only widens: a wider light block still holds every row
+    not listed as heavy, and the heavy list grows by copies of row 0 (a row
+    summed at full width is right whatever its degree).
     """
     if n_phys < graph.n_phys:
         raise ValueError(f"cannot shrink graph: {graph.n_phys} -> {n_phys}")
@@ -182,9 +186,11 @@ def pad_sparse_graph(graph: CECGraphSparse, n_phys: int,
     d_max = max(graph.d_max, d_max or 0)
     d_src = max(graph.d_src, d_src or 0)
     d_in_max = max(graph.d_in_max, d_in_max or 0)
-    if (n_phys, depth_max, d_max, d_src, d_in_max) == (
+    d_light = max(graph.d_light, d_light or 0)
+    n_heavy = max(graph.n_heavy, n_heavy or 0)
+    if (n_phys, depth_max, d_max, d_src, d_in_max, d_light, n_heavy) == (
             graph.n_phys, graph.depth_max, graph.d_max, graph.d_src,
-            graph.d_in_max):
+            graph.d_in_max, graph.d_light, graph.n_heavy):
         return graph
 
     W = graph.n_sessions
@@ -224,6 +230,9 @@ def pad_sparse_graph(graph: CECGraphSparse, n_phys: int,
     in_mask = np.zeros((n_bar, d_in_max), np.float32)
     in_mask[idx, : graph.d_in_max] = np.asarray(graph.in_mask)
 
+    heavy = np.zeros(n_heavy, np.int32)
+    heavy[: graph.n_heavy] = remap(graph.heavy)
+
     deploy = np.zeros((W, n_phys), bool)
     deploy[:, : graph.n_phys] = np.asarray(graph.deploy)
 
@@ -235,11 +244,12 @@ def pad_sparse_graph(graph: CECGraphSparse, n_phys: int,
         src_edge_mask=jnp.asarray(src_edge_mask),
         src_capacity=jnp.asarray(src_capacity),
         in_src=jnp.asarray(in_src), in_slot=jnp.asarray(in_slot),
-        in_mask=jnp.asarray(in_mask), deploy=jnp.asarray(deploy),
+        in_mask=jnp.asarray(in_mask), heavy=jnp.asarray(heavy),
+        deploy=jnp.asarray(deploy),
         sinks=jnp.asarray(n_phys + 1 + np.arange(W)),
         n_phys=n_phys, n_sessions=W, n_bar=n_bar, depth_max=depth_max,
         src=n_phys, d_max=d_max, d_src=d_src, d_in_max=d_in_max,
-        n_edges=graph.n_edges)
+        n_edges=graph.n_edges, d_light=d_light, n_heavy=n_heavy)
 
 
 @jax.tree_util.register_dataclass
@@ -267,6 +277,7 @@ class CECGraphSparseBatch:
     in_src: jax.Array
     in_slot: jax.Array
     in_mask: jax.Array
+    heavy: jax.Array
     deploy: jax.Array
     sinks: jax.Array
     # --- static metadata (shared across instances) ---
@@ -280,10 +291,12 @@ class CECGraphSparseBatch:
     d_src: int = dataclasses.field(metadata=dict(static=True))
     d_in_max: int = dataclasses.field(metadata=dict(static=True))
     n_edges: int = dataclasses.field(metadata=dict(static=True))
+    d_light: int = dataclasses.field(metadata=dict(static=True))
+    n_heavy: int = dataclasses.field(metadata=dict(static=True))
 
     _LEAVES = ("nbr", "out_mask", "edge_mask", "capacity", "sink_slot",
                "src_nbr", "src_out_mask", "src_edge_mask", "src_capacity",
-               "in_src", "in_slot", "in_mask", "deploy", "sinks")
+               "in_src", "in_slot", "in_mask", "heavy", "deploy", "sinks")
 
     @classmethod
     def from_graphs(cls,
@@ -299,7 +312,9 @@ class CECGraphSparseBatch:
             depth_max=max(g.depth_max for g in graphs),
             d_max=max(g.d_max for g in graphs),
             d_src=max(g.d_src for g in graphs),
-            d_in_max=max(g.d_in_max for g in graphs))
+            d_in_max=max(g.d_in_max for g in graphs),
+            d_light=max(g.d_light for g in graphs),
+            n_heavy=max(g.n_heavy for g in graphs))
         padded = [pad_sparse_graph(g, **kw) for g in graphs]
         leaves = {name: jnp.stack([getattr(g, name) for g in padded])
                   for name in cls._LEAVES}
@@ -313,7 +328,8 @@ class CECGraphSparseBatch:
             n_phys=self.n_phys, n_sessions=self.n_sessions, n_bar=self.n_bar,
             depth_max=self.depth_max, src=self.src, d_max=self.d_max,
             d_src=self.d_src, d_in_max=self.d_in_max,
-            n_edges=self.n_edges if n_edges is None else n_edges)
+            n_edges=self.n_edges if n_edges is None else n_edges,
+            d_light=self.d_light, n_heavy=self.n_heavy)
 
     def stacked_graph(self) -> CECGraphSparse:
         """A ``CECGraphSparse`` view whose leaves carry the instance axis.

@@ -332,6 +332,9 @@ class CECGraphSparse:
     Compute (sink) edges live in their tail's row (slot ``sink_slot[i]``);
     sink inflow is accumulated analytically (W scalars), never via the
     in-lists, so virtual-node hubs cannot inflate the padded degree.
+    The marginal recursion reads the out-rows in two blocks
+    (:func:`marginal_split`): the first ``d_light`` slots of every row, and
+    the ``n_heavy`` rows listed in ``heavy`` at full width.
     Solvers accept either representation (``core.flow`` / ``core.marginal``
     / ``core.routing`` dispatch on the type); ``core.dispatch.
     maybe_sparsify`` converts automatically past the (N, density)
@@ -353,6 +356,8 @@ class CECGraphSparse:
     in_src: jax.Array       # [Nb, Din] int32 tail; pad → 0
     in_slot: jax.Array      # [Nb, Din] int32 slot in the tail's row; pad → 0
     in_mask: jax.Array      # [Nb, Din] float {0,1}
+    # --- rows the marginal recursion gathers at full width ---
+    heavy: jax.Array        # [n_heavy] int32 rows wider than d_light
     # --- shared with the dense twin ---
     deploy: jax.Array       # [W, N] bool
     sinks: jax.Array        # [W] int
@@ -366,6 +371,8 @@ class CECGraphSparse:
     d_src: int = dataclasses.field(metadata=dict(static=True))
     d_in_max: int = dataclasses.field(metadata=dict(static=True))
     n_edges: int = dataclasses.field(metadata=dict(static=True))
+    d_light: int = dataclasses.field(metadata=dict(static=True))
+    n_heavy: int = dataclasses.field(metadata=dict(static=True))
 
     @property
     def W(self) -> int:
@@ -388,6 +395,28 @@ class CECGraphSparse:
         """[W, Nb] exogenous injection: session w's rate λ_w enters at S."""
         inject = jnp.zeros((self.n_sessions, self.n_bar), lam.dtype)
         return inject.at[:, self.src].set(lam)
+
+
+def marginal_split(edge_mask) -> tuple[int, np.ndarray]:
+    """``(d_light, heavy)``: how the marginal recursion splits the out-rows.
+
+    Rows are packed left, so a row's out-degree is one past its last slot
+    in use.  Each recursion step gathers ``N̄·d + n_heavy(d)·d_max`` slots:
+    the first ``d`` slots of every row, and the rows with more edges than
+    that at full width.  A gather on the TPU costs per index element, not
+    per byte (DESIGN.md §12.1), so ``d`` minimises that count; the split
+    engages only where it at most halves the full width's ``N̄·d_max``, and
+    is otherwise ``(d_max, no rows)``: the full-width step.
+    """
+    em = np.asarray(edge_mask) > 0
+    n_bar, d_max = em.shape
+    deg = np.where(em.any(1), d_max - np.argmax(em[:, ::-1], axis=1), 0)
+    n_wider = n_bar - np.cumsum(np.bincount(deg, minlength=d_max + 1))[1:]
+    gathered = n_bar * np.arange(1, d_max + 1) + n_wider * d_max
+    d_light = int(np.argmin(gathered)) + 1
+    if 2 * gathered[d_light - 1] > n_bar * d_max:
+        return d_max, np.zeros(0, np.int32)
+    return d_light, np.nonzero(deg > d_light)[0].astype(np.int32)
 
 
 def _pack_sparse(row_heads, row_sess, row_caps, src_heads, src_sess,
@@ -448,6 +477,7 @@ def _pack_sparse(row_heads, row_sess, row_caps, src_heads, src_sess,
             in_src[j, d], in_slot[j, d], in_mask[j, d] = i, sl, 1.0
 
     n_edges = int(edge_mask.sum() + src_edge_mask.sum())
+    d_light, heavy = marginal_split(edge_mask)
     return CECGraphSparse(
         nbr=jnp.asarray(nbr), out_mask=jnp.asarray(out_mask),
         edge_mask=jnp.asarray(edge_mask), capacity=jnp.asarray(capacity),
@@ -456,11 +486,12 @@ def _pack_sparse(row_heads, row_sess, row_caps, src_heads, src_sess,
         src_edge_mask=jnp.asarray(src_edge_mask),
         src_capacity=jnp.asarray(src_capacity),
         in_src=jnp.asarray(in_src), in_slot=jnp.asarray(in_slot),
-        in_mask=jnp.asarray(in_mask),
+        in_mask=jnp.asarray(in_mask), heavy=jnp.asarray(heavy),
         deploy=jnp.asarray(np.asarray(deploy, bool)),
         sinks=jnp.asarray(N + 1 + np.arange(W)),
         n_phys=N, n_sessions=W, n_bar=n_bar, depth_max=depth_max, src=src,
-        d_max=d_max, d_src=d_src, d_in_max=d_in, n_edges=n_edges)
+        d_max=d_max, d_src=d_src, d_in_max=d_in, n_edges=n_edges,
+        d_light=d_light, n_heavy=len(heavy))
 
 
 def sparsify(graph: CECGraph) -> CECGraphSparse:

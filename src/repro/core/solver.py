@@ -502,19 +502,30 @@ def _trace_dispatch(mode: str, graph) -> None:
     """Emit the dispatch decision on the installed obs tracer (no-op
     without one).  Runs at *trace* time — once per compilation, which is
     exactly when the decision is made; jitted steady-state intervals
-    never reach here (DESIGN.md §18.3)."""
+    never reach here (DESIGN.md §18.3).  An edge-list graph also emits
+    ``solver.marginal_split``: the out-slots one marginal-recursion step
+    gathers per session (``marginal_slots``) against the full width
+    (``slots``), and the split that sets them (``graph.marginal_split``)."""
     from repro.obs import trace as _trace
 
-    if _trace.current_tracer() is not None:
+    if _trace.current_tracer() is None:
+        return
+    _trace.instant(
+        f"solver.dispatch:{mode}", cat="dispatch",
+        args={"mode": mode, "n_bar": int(graph.n_bar),
+              "n_sessions": int(graph.n_sessions),
+              "sparse": isinstance(graph, CECGraphSparse),
+              # whether the per-phase Pallas kernels serve the jnp
+              # body's propagation and EG update (mode "sampled"/
+              # "learned"): "stitched" rather than plain jnp
+              "kernels": dispatch.use_kernels(graph.n_bar)})
+    if isinstance(graph, CECGraphSparse):
         _trace.instant(
-            f"solver.dispatch:{mode}", cat="dispatch",
-            args={"mode": mode, "n_bar": int(graph.n_bar),
-                  "n_sessions": int(graph.n_sessions),
-                  "sparse": isinstance(graph, CECGraphSparse),
-                  # whether the per-phase Pallas kernels serve the jnp
-                  # body's propagation and EG update (mode "sampled"/
-                  # "learned"): "stitched" rather than plain jnp
-                  "kernels": dispatch.use_kernels(graph.n_bar)})
+            "solver.marginal_split", cat="dispatch",
+            args={"marginal_slots": graph.n_bar * graph.d_light
+                  + graph.n_heavy * graph.d_max,
+                  "slots": graph.n_bar * graph.d_max,
+                  "d_light": graph.d_light, "n_heavy": graph.n_heavy})
 
 
 def step_with_etas(problem: Problem, config: SolverConfig,

@@ -200,21 +200,39 @@ def marginals(graph: CECGraphSparse, cost: CostFn, phi: SparsePhi, t: Array,
     ``delta`` is the marginal routing cost δφ (eq. 19) in the slot layout;
     ``dDdr[w, i]`` the broadcast scalar ∂D/∂r_i(w) (eq. 21), covering the
     virtual source row (its own slot set) exactly like the dense scan.
+
+    Each step gathers only the slots that can hold an edge
+    (``graph.marginal_split``): the first ``d_light`` slots of every row,
+    laid out slot-major (nodes on lanes), and the ``heavy`` rows at full
+    width, whose sums overlay their rows.  The light rows' dropped slots
+    hold exact zeros; with no heavy row the light block is the full width.
     """
     Dp = graph.edge_mask * cost.deriv(F.rows, graph.capacity)      # [Nb, D]
     Dp_src = graph.src_edge_mask * cost.deriv(F.src, graph.src_capacity)
     mask = graph.out_mask
+    coef = phi.rows * mask
+    coef_src = phi.src * graph.src_out_mask
+    # φ and F hold over the recursion: slice both blocks once
+    dl, heavy = graph.d_light, graph.heavy
+    nbr_l = graph.nbr[:, :dl].T                                     # [dl, Nb]
+    Dp_l = Dp[:, :dl].T
+    coef_l = coef[:, :, :dl].transpose(0, 2, 1)                     # [W, dl, Nb]
+    nbr_h, Dp_h, coef_h = graph.nbr[heavy], Dp[heavy], coef[:, heavy]
 
     def step(r, _):
-        nxt = (phi.rows * mask * (Dp[None] + r[:, graph.nbr])).sum(-1)
-        r_src = (phi.src * graph.src_out_mask
-                 * (Dp_src[None] + r[:, graph.src_nbr])).sum(-1)
+        nxt = (coef_l * (Dp_l[None] + r[:, nbr_l])).sum(1).at[:, heavy].set(
+            (coef_h * (Dp_h[None] + r[:, nbr_h])).sum(-1))
+        r_src = (coef_src * (Dp_src[None] + r[:, graph.src_nbr])).sum(-1)
         return nxt.at[:, graph.src].set(r_src), None
 
     zero = jnp.zeros_like(t)
     dDdr, _ = jax.lax.scan(step, zero, None, length=graph.depth_max)
+    # the slot layout back: the light rows' slots past d_light are masked
+    r_nbr = jnp.pad(dDdr[:, graph.nbr[:, :dl]],
+                    ((0, 0), (0, 0), (0, graph.d_max - dl)))
+    r_nbr = r_nbr.at[:, heavy].set(dDdr[:, nbr_h])
     delta = SparsePhi(
-        rows=mask * (Dp[None] + dDdr[:, graph.nbr]),
+        rows=mask * (Dp[None] + r_nbr),
         src=graph.src_out_mask * (Dp_src[None] + dDdr[:, graph.src_nbr]))
     return delta, dDdr
 

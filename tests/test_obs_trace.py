@@ -254,3 +254,37 @@ def test_wall_clock_us_holds_the_step(monkeypatch, tracer, kind):
         need = (dict(phases)["control.perturb"] + phases[after][1]
                 + phases[first_sync][1])
         assert need * 1e6 <= w <= total * 1e6
+
+
+@pytest.mark.parametrize("layout", ["dense", "edges"])
+def test_marginal_split_instant(tracer, layout):
+    """An edge-list router's step, when it compiles, emits beside its
+    ``solver.dispatch:*`` instant the out-slots one marginal-recursion
+    step gathers: on a Barabási–Albert fleet with hubs fewer than the full
+    width.  A dense graph has no slots and emits none."""
+    from repro.core import build_augmented, build_augmented_sparse
+    from repro.core.graph import draw_instance
+    from repro.topo import power_law
+
+    inst = draw_instance(power_law(300, 2), 3, 10.0, 0)
+    build = build_augmented_sparse if layout == "edges" else build_augmented
+    graph = build(power_law(300, 2), inst.deploy, inst.link_capacity,
+                  inst.compute_capacity)
+    router = CECRouter(graph, lam_total=60.0)
+    router.control_step(
+        lambda lams: (25.0 * np.log1p(0.3 * np.asarray(lams))).sum(-1))
+    names = [e["name"] for e in tracer.events]
+    assert "solver.dispatch:sampled" in names
+    split = [e["args"] for e in tracer.events
+             if e["name"] == "solver.marginal_split"]
+    if layout == "dense":
+        assert split == []
+        return
+    (args,) = split
+    assert args == {
+        "marginal_slots": graph.n_bar * graph.d_light
+        + graph.n_heavy * graph.d_max,
+        "slots": graph.n_bar * graph.d_max,
+        "d_light": graph.d_light, "n_heavy": graph.n_heavy}
+    assert 0 < args["n_heavy"] and args["d_light"] < graph.d_max
+    assert args["marginal_slots"] < args["slots"]

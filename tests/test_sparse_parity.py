@@ -5,8 +5,11 @@ property-based checks over random graphs, random alive-masks and random φ
 for flow propagation, total cost, the marginal-cost broadcast, one OMD
 step, and a full ``solve_jowr`` run — all within 1e-5 of the dense path —
 plus structural identity of the two sparse constructors, pad/batch
-equivalence, and the Pallas sparse-kernel dispatch.
+equivalence, the marginal recursion's light/heavy split of the out-rows,
+and the Pallas sparse-kernel dispatch.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,7 +28,7 @@ from repro.core import sparse as sp
 from repro.core.flow import cost_and_state
 from repro.core.graph import draw_instance
 from repro.core.marginal import marginals
-from repro.topo import connected_er
+from repro.topo import connected_er, power_law
 
 from conftest import random_phi
 
@@ -154,6 +157,57 @@ def test_marginal_parity(seed, cost_name):
                                rtol=1e-5, atol=1e-5)
 
 
+# graph kinds for the split: a Barabási–Albert fleet with hubs, on which
+# the rule engages (d_light, n_heavy as computed from its degrees), and
+# Connected-ER draws of near-uniform degree, on which it does not
+SPLIT_KINDS = {"power_law": (power_law(300, 2), 0, (36, 6, 16)),
+               "er16": (connected_er(16, 0.35, seed=0), 0, None),
+               "er40": (connected_er(40, 0.35, seed=1), 1, None)}
+
+
+def _full_width(gs):
+    """The same graph with the split off: every row at full width."""
+    return dataclasses.replace(gs, heavy=jnp.zeros(0, jnp.int32),
+                               d_light=gs.d_max, n_heavy=0)
+
+
+@pytest.mark.parametrize("kind", sorted(SPLIT_KINDS))
+def test_marginal_split_parity(kind):
+    """The split recursion equals the full-width one (1e-6 relative) and
+    the dense path (1e-5); the split engages only where it at most halves
+    the slots each step gathers."""
+    adj, seed, split = SPLIT_KINDS[kind]
+    g = draw_instance(adj, 3, 10.0, seed).graph
+    gs, phi, phis = _sparse_pair(g, seed)
+    if split is None:
+        assert (gs.d_light, gs.n_heavy) == (gs.d_max, 0)
+    else:
+        assert (gs.d_max, gs.d_light, gs.n_heavy) == split
+        assert 2 * (gs.n_bar * gs.d_light + gs.n_heavy * gs.d_max) \
+            <= gs.n_bar * gs.d_max
+    # every row wider than the light block is gathered at full width
+    em = np.asarray(gs.edge_mask) > 0
+    wide = np.nonzero(em[:, gs.d_light:].any(1))[0]
+    assert set(wide) <= set(np.asarray(gs.heavy).tolist())
+    lam = _lam(g, seed)
+    _, t_d, F_d = cost_and_state(g, COST, phi, lam)
+    delta_d, dDdr_d = marginals(g, COST, phi, t_d, F_d)
+    _, t_s, F_s = cost_and_state(gs, COST, phis, lam)
+    delta_s, dDdr_s = marginals(gs, COST, phis, t_s, F_s)
+    delta_f, dDdr_f = marginals(_full_width(gs), COST, phis, t_s, F_s)
+    np.testing.assert_allclose(np.asarray(dDdr_s), np.asarray(dDdr_f),
+                               rtol=1e-6, atol=0)
+    for a, b in zip(delta_s, delta_f):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=0)
+    np.testing.assert_allclose(np.asarray(dDdr_d), np.asarray(dDdr_s),
+                               rtol=1e-5, atol=1e-5)
+    m = np.asarray(g.out_mask) > 0
+    np.testing.assert_allclose(np.asarray(delta_d)[m],
+                               np.asarray(sp.phi_to_dense(gs, delta_s))[m],
+                               rtol=1e-5, atol=1e-5)
+
+
 @settings(max_examples=10, deadline=None)
 @given(seed=st.integers(0, 10_000), eta=st.floats(0.1, 5.0))
 def test_omd_step_parity(seed, eta):
@@ -257,6 +311,58 @@ def test_pad_sparse_graph_solve_equivalent(small_cec):
     _, tr1 = solve_routing(padded, COST, lam, padded.uniform_phi(), 1.0, 20)
     np.testing.assert_allclose(np.asarray(tr0), np.asarray(tr1),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_pad_sparse_graph_keeps_heavy_rows():
+    """Relocation keeps the heavy rows (physical nodes keep their indices),
+    and a wider split (a batch's) pads them with row 0 and stays exact."""
+    g = draw_instance(power_law(300, 2), 3, 10.0, 0).graph
+    gs, _, phis = _sparse_pair(g, 0)
+    n_pad = 7
+    padded = pad_sparse_graph(gs, gs.n_phys + n_pad, d_max=gs.d_max + 3,
+                              d_light=gs.d_light + 1,
+                              n_heavy=gs.n_heavy + 5)
+    assert (padded.d_light, padded.n_heavy) == (gs.d_light + 1,
+                                                gs.n_heavy + 5)
+    heavy = np.asarray(padded.heavy)
+    np.testing.assert_array_equal(heavy[: gs.n_heavy], np.asarray(gs.heavy))
+    assert (heavy[gs.n_heavy:] == 0).all()
+    em = np.asarray(padded.edge_mask) > 0
+    assert set(np.nonzero(em[:, padded.d_light:].any(1))[0]) <= set(heavy)
+    lam = _lam(gs, 0)
+    _, t0, F0 = cost_and_state(gs, COST, phis, lam)
+    _, dDdr0 = marginals(gs, COST, phis, t0, F0)
+    phip = padded.uniform_phi()
+    phip = SparsePhi(
+        rows=phip.rows.at[:, : gs.n_phys, : gs.d_max].set(
+            phis.rows[:, : gs.n_phys]),
+        src=phip.src.at[:, : gs.d_src].set(phis.src))
+    _, t1, F1 = cost_and_state(padded, COST, phip, lam)
+    _, dDdr1 = marginals(padded, COST, phip, t1, F1)
+    np.testing.assert_allclose(np.asarray(dDdr0)[:, : gs.n_phys],
+                               np.asarray(dDdr1)[:, : gs.n_phys],
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_sparse_batch_with_rows_heavy_in_one_instance():
+    """A batch whose instances split their out-rows differently solves as
+    each instance does alone: a row heavy in one is light in another."""
+    graphs = [sparsify(draw_instance(adj, 3, 10.0, s).graph) for adj, s in
+              [(power_law(300, 2, seed=0), 0), (power_law(260, 2, seed=1), 1),
+               (connected_er(40, 0.35, seed=1), 1)]]
+    heavy = [set(np.asarray(g.heavy).tolist()) for g in graphs]
+    n = min(g.n_phys for g in graphs[:2])
+    assert any(r < n for r in heavy[0] - heavy[1])
+    assert any(r < n for r in heavy[1] - heavy[0])
+    sb = CECGraphSparseBatch.from_graphs(graphs)
+    assert sb.d_light == max(g.d_light for g in graphs)
+    assert sb.n_heavy == max(g.n_heavy for g in graphs)
+    lam = jnp.array([8.0, 12.0, 16.0])
+    _, tr_b = solve_routing_batch(sb, COST, lam, sb.uniform_phi(), 1.0, 15)
+    for b, g in enumerate(graphs):
+        _, tr = solve_routing(g, COST, lam, g.uniform_phi(), 1.0, 15)
+        np.testing.assert_allclose(np.asarray(tr_b[b]), np.asarray(tr),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_sparse_batch_matches_dense_batch():

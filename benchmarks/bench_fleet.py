@@ -28,7 +28,7 @@ from repro.core.batch import CECGraphBatch, run_batch, run_batch_sharded
 from repro.core.graph import build_random_cec
 from repro.core.solver import SolverConfig
 from repro.core.utility import make_bank
-from repro.launch.mesh import fleet_mesh
+from repro.core.mesh import fleet_mesh
 from repro.topo import make_fleet
 
 from . import common

@@ -7,7 +7,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core import build_random_cec, get_cost, omd_step
 from repro.kernels import ref
@@ -62,17 +61,6 @@ def main() -> list[dict]:
         emit(f"kernels.omd_step.n{n}", secs,
              f"v5e_fused_est_us={v5e_est*1e6:.2f}")
 
-    # flash-attention oracle FLOPs check (ref path, small shape)
-    S = common.scaled(512, 128)
-    q = jax.random.normal(jax.random.PRNGKey(0), (1, 8, S, 64), jnp.float32)
-    k = jax.random.normal(jax.random.PRNGKey(1), (1, 8, S, 64), jnp.float32)
-    v = jax.random.normal(jax.random.PRNGKey(2), (1, 8, S, 64), jnp.float32)
-    att = jax.jit(lambda a, b, c: ref.mha_ref(a, b, c, causal=True))
-    _, secs = timeit(att, q, k, v, warmup=1, iters=3)
-    flops = 4 * 8 * S * S * 64 / 2  # causal
-    rows.append({"bench": f"mha_ref_{S}", "cpu_s": secs,
-                 "gflops_cpu": flops / secs / 1e9})
-    emit(f"kernels.mha_ref_{S}", secs, f"gflops={flops/secs/1e9:.2f}")
     dump("bench_kernels", rows)
     return rows
 

@@ -2,7 +2,7 @@
 
 ``PYTHONPATH=src python -m benchmarks.run [--only fig7,...] [--smoke]``
 prints ``name,us_per_call,derived`` CSV rows and writes JSON artifacts to
-experiments/paper/ (consumed by EXPERIMENTS.md).
+experiments/paper/.
 
 ``--smoke`` is the CI gate (tiny sizes, 1 warmup / 1 iter — see
 ``common.set_smoke``): it exercises every module's kernel and batch paths

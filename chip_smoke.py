@@ -278,7 +278,7 @@ def four_chips() -> None:
     from repro.core import CECGraphBatch, make_bank, run_batch
     from repro.core.batch import run_batch_sharded
     from repro.core.solver import serving_defaults
-    from repro.launch.mesh import fleet_mesh
+    from repro.core.mesh import fleet_mesh
     from repro.obs import trace
 
     devices = jax.devices()

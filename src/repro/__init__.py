@@ -12,7 +12,7 @@ and drive it with ``init``/``step``/``run``::
 keyword-compatible shims over the same engine; ``run_scenario`` threads
 its state across non-stationary segments and ``CECRouter`` serves it
 live.  Everything is re-exported lazily so ``import repro`` stays cheap
-— the serving/model stack loads only when touched.
+— the serving plane loads only when touched.
 
 ``tests/test_public_api.py`` pins ``__all__`` and the entry-point
 signatures; extend both together.
@@ -34,10 +34,9 @@ _CORE_EXPORTS = (
     "UtilityFamily", "get_family", "fit_utilities", "OnlineFitter",
     "fixed_point_solve", "tune_etas",
 )
-# names resolved from repro.serve on first access (pulls the model stack)
-_SERVE_EXPORTS = ("CECRouter", "InferenceEngine", "ServingSim")
-_SUBMODULES = ("core", "configs", "topo", "kernels", "serve", "parallel",
-               "models", "train", "optim", "data", "launch", "roofline",
+# names resolved from repro.serve on first access
+_SERVE_EXPORTS = ("CECRouter",)
+_SUBMODULES = ("core", "configs", "topo", "kernels", "serve", "roofline",
                "obs")
 
 __all__ = [*_CORE_EXPORTS, *_SERVE_EXPORTS, *_SUBMODULES]

@@ -1,32 +1,1 @@
-"""Architecture registry: ``--arch <id>`` → ModelConfig.
-
-Also exposes the paper's own CEC scenario config (cec_paper) and the
-assigned shape table (shapes.SHAPES).
-"""
-from __future__ import annotations
-
-import importlib
-
-from repro.models.config import ModelConfig
-
-from .shapes import SHAPES, ShapeSpec, applicable  # noqa: F401
-
-_MODULES = {
-    "deepseek-coder-33b": "deepseek_coder_33b",
-    "phi4-mini-3.8b": "phi4_mini_3_8b",
-    "granite-3-2b": "granite_3_2b",
-    "smollm-135m": "smollm_135m",
-    "jamba-1.5-large-398b": "jamba_1_5_large_398b",
-    "whisper-large-v3": "whisper_large_v3",
-    "qwen2-moe-a2.7b": "qwen2_moe_a2_7b",
-    "moonshot-v1-16b-a3b": "moonshot_v1_16b_a3b",
-    "xlstm-1.3b": "xlstm_1_3b",
-    "qwen2-vl-72b": "qwen2_vl_72b",
-}
-
-ARCH_IDS = tuple(_MODULES)
-
-
-def get_config(arch: str, smoke: bool = False) -> ModelConfig:
-    mod = importlib.import_module(f"repro.configs.{_MODULES[arch]}")
-    return mod.SMOKE if smoke else mod.CONFIG
+"""The paper's own CEC scenario config (``cec_paper``)."""

@@ -32,6 +32,8 @@ from . import dispatch
 from . import solver as _solver
 from .allocation import JOWRResult
 from .graph import CECGraph, CECGraphSparse
+from .mesh import (fleet_axis, fleet_mesh, fleet_padded_size, fleet_specs,
+                   pad_fleet, shard_map_compat, unpad_fleet)
 from .problem import Problem, resolve_cost
 from .routing import solve_routing, solve_routing_sgp
 from .solver import Method, SolverConfig, SolverState
@@ -510,14 +512,14 @@ def run_batch_sharded(
     The fleet axis of every stacked pytree — the batch's graph leaves,
     per-instance banks, a carried ``SolverState``, ``phi0``/``lam0``
     overrides — is partitioned across ``mesh`` (default: the 1-D
-    ``launch.mesh.fleet_mesh()`` over all visible devices) with
+    ``core.mesh.fleet_mesh()`` over all visible devices) with
     ``shard_map``; each device vmaps the solver core over its local
     shard.  The per-shard solves are embarrassingly parallel, so the
     mapped body contains no collectives and no host callbacks — the
     whole scan stays device-resident.
 
     Fleets that do not divide the mesh are padded with replicas of the
-    last instance (``parallel.sharding.pad_fleet``) and the pad lanes
+    last instance (``core.mesh.pad_fleet``) and the pad lanes
     are sliced off the result (``unpad_fleet``) — exact masking, not an
     approximation: the returned ``Result`` matches :func:`run_batch`
     lane-for-lane (bit-identical on a 1-device mesh, ≤1e-6 across
@@ -530,11 +532,6 @@ def run_batch_sharded(
     covers the mesh shape and cached jitted consumers never alias
     executables across meshes.
     """
-    from repro.launch.mesh import fleet_mesh
-    from repro.parallel.collectives import shard_map_compat
-    from repro.parallel.sharding import (fleet_axis, fleet_padded_size,
-                                         fleet_specs, pad_fleet, unpad_fleet)
-
     if not isinstance(banks, UtilityBank):
         banks = stack_banks(list(banks))
     costfn = resolve_cost(cost)

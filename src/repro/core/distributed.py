@@ -19,8 +19,8 @@ physical network:
 
 ``solve_routing_sharded`` jits the full loop with those shardings; the
 Pallas kernels (flow_step / omd_update) are the per-shard compute bodies
-on real TPUs.  Tested on a fake 8-device mesh in tests/test_parallel.py
-and dry-run-compiled at N=4096 on the 16×16 production mesh.
+on real TPUs.  Tested on a fake 8-device mesh in
+tests/test_distributed_core.py.
 
 Sharding and sparsity are complementary scale axes: this module shards the
 *dense* [W, N, N] state across a mesh, while ``core/sparse.py`` shrinks

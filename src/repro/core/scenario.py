@@ -258,8 +258,8 @@ def event_schedule(scenario: Scenario
 
     The first entry is ``(0, ())`` — the initial segment.  This is the one
     definition of "when does what fire": :func:`compile_segments` consumes
-    it for the offline batched sweeps and the serving simulation
-    (``serve/sim.py``) replays the same schedule against the live router,
+    it for the offline batched sweeps, and a serving loop replays the same
+    schedule against the live router (``CECRouter.apply_scenario_event``),
     so what is benchmarked is what serves (DESIGN.md §11).
     """
     bounds = (0,) + scenario.event_times + (scenario.horizon,)
@@ -378,7 +378,7 @@ def run_scenario(
     ``solve_jowr`` (the static engine) — asserted to machine precision
     in the tests.
 
-    ``mesh`` (a 1-D fleet mesh, see ``launch.mesh.fleet_mesh``) runs
+    ``mesh`` (a 1-D fleet mesh, see ``core.mesh.fleet_mesh``) runs
     every segment on the sharded fleet driver
     (:func:`core.batch.run_batch_sharded`): the seed axis is partitioned
     across the mesh, warm-starts included — large seed ensembles scale

@@ -22,10 +22,8 @@ import jax.numpy as jnp
 
 from . import ref
 from .control_megakernel import control_step_dense, control_step_sparse
-from .flash_attention import flash_attention
 from .flow_step import flow_step
 from .flow_step_sparse import flow_step_sparse
-from .mamba_scan import mamba_scan
 from .omd_update import omd_update
 from .omd_update_sparse import omd_update_sparse
 
@@ -57,23 +55,6 @@ def _pad_axis_to(x, axis: int, size: int, value=0.0):
 
 def _round_up(n: int, mult: int = 128) -> int:
     return ((n + mult - 1) // mult) * mult
-
-
-@partial(jax.jit, static_argnames=("causal", "q_offset", "kv_len",
-                                   "interpret"))
-def flash_attention_op(q, k, v, causal=True, q_offset=0, kv_len=None, *,
-                       interpret):
-    """Padded/sliced flash attention; q [B,H,S,hd], k/v [B,KH,T,hd]."""
-    S, T = q.shape[2], k.shape[2]
-    kv_len = T if kv_len is None else kv_len
-    bq = 512 if S >= 512 else max(8, S)
-    bk = 512 if T >= 512 else max(8, T)
-    qp = _pad_to(q, 2, bq)
-    kp = _pad_to(k, 2, bk)
-    vp = _pad_to(v, 2, bk)
-    out = flash_attention(qp, kp, vp, causal=causal, q_offset=q_offset,
-                          kv_len=kv_len, bq=bq, bk=bk, interpret=interpret)
-    return out[:, :, :S]
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -225,20 +206,6 @@ def control_step_sparse_op(lam, rows, src_phi, task_u, lam_total, graph,
             d_o[0, 0])
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def mamba_scan_op(u, dt, A, Bm, Cm, *, interpret):
-    """Padded chunkwise SSM scan; pads di→128-multiple, S→chunk multiple."""
-    B, S, di = u.shape
-    ck = 128 if S >= 128 else S
-    up = _pad_to(_pad_to(u, 1, ck), 2, 128)
-    dtp = _pad_to(_pad_to(dt, 1, ck), 2, 128)
-    Ap = _pad_to(A, 0, 128)
-    Bp = _pad_to(Bm, 1, ck)
-    Cp = _pad_to(Cm, 1, ck)
-    out = mamba_scan(up, dtp, Ap, Bp, Cp, ck=ck, interpret=interpret)
-    return out[:, :S, :di]
-
-
-__all__ = ["control_step_op", "control_step_sparse_op", "flash_attention_op",
+__all__ = ["control_step_op", "control_step_sparse_op",
            "flow_in_edges_sparse", "flow_step_op", "flow_step_sparse_op",
-           "mamba_scan_op", "omd_update_op", "omd_update_sparse_op", "ref"]
+           "omd_update_op", "omd_update_sparse_op", "ref"]

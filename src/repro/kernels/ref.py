@@ -1,13 +1,10 @@
 """Pure-jnp oracles for every Pallas kernel (the correctness contract).
 
 Each kernel's test sweeps shapes/dtypes and asserts allclose against these.
-They are also the CPU / dry-run execution paths.
+They are also the CPU execution paths.
 """
 from __future__ import annotations
 
-import math
-
-import jax
 import jax.numpy as jnp
 
 Array = jnp.ndarray
@@ -51,41 +48,3 @@ def omd_update_sparse_ref(phi: Array, delta: Array, mask: Array,
     representation-agnostic; only the trailing-axis meaning differs.
     """
     return omd_update_ref(phi, delta, mask, eta)
-
-
-def mha_ref(q: Array, k: Array, v: Array, causal: bool = True,
-            q_offset: int = 0, kv_len: int | None = None) -> Array:
-    """Dense GQA attention. q [B,H,S,hd]; k,v [B,KH,T,hd] → [B,H,S,hd]."""
-    B, H, S, hd = q.shape
-    KH, T = k.shape[1], k.shape[2]
-    G = H // KH
-    qg = q.reshape(B, KH, G, S, hd)
-    s = jnp.einsum("bkgsd,bktd->bkgst", qg, k) / math.sqrt(hd)
-    kpos = jnp.arange(T)
-    mask = kpos[None, :] < (kv_len if kv_len is not None else T)
-    if causal:
-        qpos = q_offset + jnp.arange(S)
-        mask = mask & (kpos[None, :] <= qpos[:, None])
-    s = jnp.where(mask[None, None, None], s.astype(jnp.float32), -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    o = jnp.einsum("bkgst,bktd->bkgsd", p, v)
-    return o.reshape(B, H, S, hd)
-
-
-def mamba_scan_ref(u: Array, dt: Array, A: Array, Bm: Array,
-                   Cm: Array) -> Array:
-    """Sequential selective-SSM reference: y_t = ⟨h_t, C_t⟩ with
-    h_t = exp(dt_t·A)h_{t−1} + (dt_t·u_t)B_t.  u,dt [B,S,di]; A [di,ds]."""
-    B, S, di = u.shape
-
-    def step(h, xs):
-        u_t, dt_t, B_t, C_t = xs
-        dA = jnp.exp(dt_t[..., None] * A)
-        h = dA * h + (dt_t * u_t)[..., None] * B_t[:, None, :]
-        return h, jnp.einsum("bds,bs->bd", h, C_t)
-
-    h0 = jnp.zeros((B, di, A.shape[1]), jnp.float32)
-    xs = tuple(jnp.moveaxis(x.astype(jnp.float32), 1, 0)
-               for x in (u, dt, Bm, Cm))
-    _, ys = jax.lax.scan(step, h0, xs)
-    return jnp.moveaxis(ys, 0, 1).astype(u.dtype)

@@ -1,4 +1,4 @@
-"""Three-term roofline from the dry-run's compiled artifacts.
+"""Three-term roofline for compiled programs.
 
 Hardware model (TPU v5e, per chip):
   peak bf16 compute   197 TFLOP/s
@@ -10,16 +10,9 @@ Terms (seconds per step, per chip):
   memory     = HLO_bytes / (chips · 819e9)
   collective = per-chip wire bytes / 50e9
 
-plus MODEL_FLOPS = 6·N·D (train) or 2·N_active·D (inference) and the
-usefulness ratio MODEL_FLOPS / HLO_FLOPs (catches remat/redundancy waste).
-
-Run ``python -m repro.roofline.analysis`` after the dry-run to render the
-§Roofline table from experiments/dryrun/*.json.
+``roofline.extract`` places the control-step programs on these axes.
 """
 from __future__ import annotations
-
-import json
-import pathlib
 
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
@@ -38,138 +31,3 @@ def roofline_terms(flops: float, hbm_bytes: float, wire_bytes: float,
     total = max(compute, memory, collective)
     terms["roofline_fraction"] = compute / total if total > 0 else 0.0
     return terms
-
-
-def analytic_bytes(cfg, shape, chips: int, *, param_bytes: int = 2,
-                   kv_bytes: int = 2, moment_bytes: int = 4) -> float:
-    """Fusion-aware HBM traffic model (per chip, per step).
-
-    The raw ``cost_analysis`` byte count assumes zero fusion (every
-    elementwise op re-reads its operands from HBM), which overstates TPU
-    traffic ~5–10×.  This model counts what a fused execution moves:
-
-      params   — read 3× in train (fwd + remat + bwd) or 1× serving,
-                 + grads (f32 w+r) + optimizer state r/w in train
-      acts     — ~12 HBM-resident tensors of B·S·d per layer per train
-                 step (fwd write, bwd read, remat re-write), 4 for prefill
-      attn     — flash traffic: Q/O once + KV per q-block pass
-      kv cache — decode reads the full cache, writes one token
-      states   — recurrent state crosses HBM at chunk boundaries only
-                 (the chunkwise kernel keeps it in VMEM within a chunk)
-      logits   — B·S·V bf16 + f32 loss pass (train)
-    """
-    B, S = shape.global_batch, shape.seq_len
-    P = cfg.approx_params()
-    L = cfg.n_layers
-    d = cfg.d_model
-    n = cfg.n_periods
-    n_attn = sum(1 for m, _ in cfg.period if m == "attn") * n
-    kind = shape.kind
-    tokens = B * S if kind != "decode" else B
-
-    total = 0.0
-    if kind == "train":
-        total += P * (3 * param_bytes + 2 * 4 + 3 * 2 * moment_bytes)
-        total += tokens * d * L * 2 * 12
-        total += B * S * cfg.vocab * (2 + 2 * 4)
-    elif kind == "prefill":
-        total += P * param_bytes
-        total += tokens * d * L * 2 * 4
-        total += n_attn * B * S * cfg.n_kv_heads * cfg.hd * 2 * kv_bytes
-    else:  # decode
-        total += P * param_bytes
-        total += n_attn * B * S * cfg.n_kv_heads * cfg.hd * 2 * kv_bytes
-        total += B * cfg.vocab * 2
-
-    # flash attention traffic (self-attn, q-block 512)
-    if kind in ("train", "prefill") and n_attn:
-        passes = 4 if kind == "train" else 1
-        bq = 512
-        total += passes * n_attn * (
-            2 * B * S * cfg.n_heads * cfg.hd * 2
-            + max(S // bq, 1) * B * S * cfg.n_kv_heads * cfg.hd * kv_bytes)
-
-    # recurrent state at chunk boundaries (chunk = 64)
-    rec_state = {"mamba": cfg.d_inner * cfg.d_state,
-                 "mlstm": cfg.n_heads * cfg.hd ** 2,
-                 "slstm": 4 * cfg.n_heads * cfg.hd}
-    for mixer, _ in cfg.period:
-        if mixer in rec_state:
-            steps = S if kind != "decode" else 1
-            crossings = max(steps // 64, 1) * (4 if kind == "train" else 1)
-            total += n * crossings * B * rec_state[mixer] * 4 * 2
-    return total / chips
-
-
-def model_flops(cfg, shape_kind: str, seq: int, batch: int) -> float:
-    n = cfg.active_params()
-    if shape_kind == "train":
-        return 6.0 * n * seq * batch
-    if shape_kind == "prefill":
-        return 2.0 * n * seq * batch
-    return 2.0 * n * batch               # decode: one token per sequence
-
-
-def summarize(record: dict) -> dict:
-    t = roofline_terms(record["flops"], record["hbm_bytes"],
-                       record["wire_bytes_per_chip"], record["chips"])
-    t["useful_ratio"] = (record["model_flops"] / record["flops"]
-                         if record["flops"] else 0.0)
-    return t
-
-
-_NOTES = {
-    "compute": "raise arithmetic intensity: larger microbatch per chip or "
-               "fewer remat recomputes",
-    "memory": "cut HBM traffic: fuse attention (flash), keep KV in bf16, "
-              "larger matmul tiles",
-    "collective": "reshard to cut gathers: 2D-sharded weights with "
-                  "overlapped FSDP prefetch, compressed grads, EP a2a",
-}
-
-
-def _row(r: dict, terms: dict) -> str:
-    note = _NOTES[terms["bottleneck"]]
-    return (
-        f"| {r['arch']} | {r['shape']} | {terms['compute_s']*1e3:.2f} | "
-        f"{terms['memory_s']*1e3:.2f} | {terms['collective_s']*1e3:.2f} | "
-        f"{terms['bottleneck']} | {terms['useful_ratio']:.3f} | "
-        f"{terms['roofline_fraction']:.3f} | {note} |")
-
-
-def render_table(roofline_dir: str = "experiments/roofline",
-                 adjusted: bool = False) -> str:
-    rows = []
-    for f in sorted(pathlib.Path(roofline_dir).glob("*.json")):
-        r = json.loads(f.read_text())
-        if r.get("status") == "skipped":
-            rows.append(f"| {r['arch']} | {r['shape']} | — | — | — | "
-                        f"skipped: {r['reason']} | — | — | — |")
-            continue
-        src = r.get("flash_adjusted", r) if adjusted else r
-        flops = src.get("flops", r["flops"])
-        hbm = src.get("bytes", r.get("hbm_bytes"))
-        wire = src.get("wire", r.get("wire_bytes_per_chip"))
-        if adjusted:
-            # fusion-aware memory model replaces the no-fusion HLO bytes
-            from repro.configs import SHAPES, get_config
-            cfg = get_config(r["arch"])
-            big = cfg.approx_params() > 100e9
-            pb = 2 if (big or r["shape"] != "train_4k") else 4
-            mb = 2 if big else 4
-            hbm = analytic_bytes(cfg, SHAPES[r["shape"]], r["chips"],
-                                 param_bytes=pb, moment_bytes=mb)
-        t = roofline_terms(flops, hbm, wire, 1)   # inputs are per chip
-        t["useful_ratio"] = r["model_flops"] / flops if flops else 0.0
-        rows.append(_row(r, t))
-    head = ("| arch | shape | compute ms | memory ms | collective ms | "
-            "bottleneck | 6ND/HLO | roofline frac | lever |\n"
-            "|---|---|---|---|---|---|---|---|---|")
-    return head + "\n" + "\n".join(rows)
-
-
-if __name__ == "__main__":
-    import sys
-
-    adj = "--adjusted" in sys.argv
-    print(render_table(adjusted=adj))

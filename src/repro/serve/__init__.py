@@ -1,11 +1,8 @@
 from .cec_router import CECRouter
-from .engine import InferenceEngine, Request
 from .fleet import FleetView, RouterFleet
-from .sim import ServingSim, SimReport
 from .traffic import (TrafficTrace, diurnal_trace, flash_crowd_trace,
                       named_traces, poisson_trace, scenario_base_demand)
 
-__all__ = ["CECRouter", "InferenceEngine", "Request", "ServingSim",
-           "SimReport", "RouterFleet", "FleetView", "TrafficTrace",
+__all__ = ["CECRouter", "RouterFleet", "FleetView", "TrafficTrace",
            "poisson_trace", "diurnal_trace", "flash_crowd_trace",
            "named_traces", "scenario_base_demand"]

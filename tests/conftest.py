@@ -1,7 +1,7 @@
 import os
 
-# Tests must see the single real CPU device (the 512-device fake platform is
-# reserved for launch/dryrun.py, which sets XLA_FLAGS before importing jax).
+# Tests must see the single real CPU device (multi-device tests run in
+# subprocesses that set XLA_FLAGS before importing jax).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import numpy as np

@@ -11,7 +11,7 @@ except ModuleNotFoundError:
 from repro.core import build_random_cec
 from repro.core.graph import InfeasibleTopology, build_augmented, random_deployment
 from repro.topo import (abilene, balanced_tree, connected_er, fog, geant,
-                        make_topology)
+                        make_fleet, make_topology, power_law)
 
 
 def _is_dag(edge_mask: np.ndarray) -> bool:
@@ -86,6 +86,48 @@ def test_topology_generators(name, n, degmin):
     assert not adj.diagonal().any()
     assert (adj.sum(0) >= degmin).all()
     assert cbar > 0
+
+
+def _is_connected(adj: np.ndarray) -> bool:
+    seen, frontier = {0}, [0]
+    while frontier:
+        for j in np.nonzero(adj[frontier.pop()])[0]:
+            if int(j) not in seen:
+                seen.add(int(j))
+                frontier.append(int(j))
+    return len(seen) == adj.shape[0]
+
+
+# (kind, n, m, seed): grid_2d and random_geometric through make_fleet;
+# power_law with its attachment count m, which make_fleet fixes at 2
+FLEET_CASES = [("grid_2d", 64, None, 0), ("grid_2d", 256, None, 0),
+               *[("random_geometric", n, None, s)
+                 for n in (64, 256) for s in (0, 1)],
+               *[("power_law", 300, m, s) for m in (2, 3) for s in (0, 1)]]
+
+
+def _fleet(kind, n, m, seed):
+    if kind == "power_law":
+        return power_law(n, m, seed=seed)
+    return make_fleet(kind, n, seed=seed)
+
+
+@pytest.mark.parametrize("kind,n,m,seed", FLEET_CASES,
+                         ids=[f"{k}-n{n}" + (f"-m{m}" if m else "")
+                              + f"-s{s}" for k, n, m, s in FLEET_CASES])
+def test_fleet_generators(kind, n, m, seed):
+    """The fleet-scale generators: n nodes, a symmetric boolean matrix
+    with an empty diagonal, connected, and the same matrix from the same
+    seed; Barabási–Albert has its exact link count (a clique of m+1
+    seeds, then m links per later node)."""
+    adj = _fleet(kind, n, m, seed)
+    assert adj.dtype == bool and adj.shape == (n, n)
+    assert (adj == adj.T).all()
+    assert not adj.diagonal().any()
+    assert _is_connected(adj)
+    np.testing.assert_array_equal(adj, _fleet(kind, n, m, seed))
+    if kind == "power_law":
+        assert adj.sum() // 2 == (m + 1) * m // 2 + m * (n - m - 1)
 
 
 def test_paper_table2_shapes():

@@ -19,14 +19,13 @@ def test_sharded_routing_matches_reference():
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import build_random_cec, get_cost, solve_routing
 from repro.core.distributed import solve_routing_sharded
-from repro.launch.mesh import make_mesh
 from repro.topo import connected_er
 
 # n chosen so n_bar = 29 pads awkwardly → exercises uneven shard fallback?
 # use 28 phys nodes → n_bar = 32, divisible by the 4×2 mesh
 g = build_random_cec(connected_er(28, 0.25, seed=3), 3, 10.0, seed=0)
 assert g.n_bar % 8 == 0, g.n_bar
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"))
 cost = get_cost("exp")
 lam = jnp.array([15.0, 20.0, 25.0])
 phi0 = g.uniform_phi()
@@ -45,9 +44,9 @@ print("SHARDED_OK")
 def test_control_plane_lowers_at_fleet_scale():
     """N=2048-node control plane compiles SPMD on an 8-device mesh."""
     out = _run("""
+import jax
 from repro.core.distributed import lower_control_plane
-from repro.launch.mesh import make_mesh
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"))
 compiled = lower_control_plane(2045, 3, mesh, n_iters=5)
 ca = compiled.cost_analysis()
 ca = ca[0] if isinstance(ca, (list, tuple)) else ca

@@ -10,15 +10,19 @@ import pytest
 
 from repro.core import solver as _solver
 from repro.core.batch import fused_step_batch
-from repro.core.scenario import (event_schedule, initial_state,
+from repro.core.scenario import (Scenario, event_schedule, initial_state,
                                  named_scenarios)
 from repro.serve import CECRouter, RouterFleet
 
 PARITY_ATOL = 1e-5     # the ISSUE acceptance bar; in practice bit-identical
 
 
-def _make_tenants(n_tenants, *, scenario="steady", horizon=20, n=10, p=0.4):
-    sc = named_scenarios(horizon=horizon, n=n, p=p)[scenario]
+def _make_tenants(n_tenants, *, scenario="steady", horizon=20, n=10, p=0.4,
+                  topology="connected_er"):
+    if topology == "connected_er":
+        sc = named_scenarios(horizon=horizon, n=n, p=p)[scenario]
+    else:                   # a paper topology, its Table II capacity
+        sc = Scenario(scenario, horizon=horizon, topology=topology)
     states = [initial_state(sc, seed=s) for s in range(n_tenants)]
     graphs = [st.graph() for st in states]
     fns = [
@@ -34,10 +38,12 @@ def _donation_supported():
     return x.is_deleted()
 
 
-def test_fleet_parity_with_independent_routers():
+@pytest.mark.parametrize("topology",
+                         ["connected_er", "abilene", "geant", "fog"])
+def test_fleet_parity_with_independent_routers(topology):
     """K stacked tenants advance exactly like K CECRouters: same Λ, same
     net utility, same replica weights, interval for interval."""
-    sc, _, graphs, fns = _make_tenants(3)
+    sc, _, graphs, fns = _make_tenants(3, topology=topology)
     lam_totals = [60.0, 45.0, 75.0]
     routers = [CECRouter(g, lam_total=lt)
                for g, lt in zip(graphs, lam_totals)]

@@ -207,7 +207,7 @@ def test_sharded_fleet_inherits_megakernel():
     the megakernel and matches the unsharded vmap path."""
     from repro.core import CECGraphBatch, make_bank, run_batch
     from repro.core.batch import run_batch_sharded
-    from repro.launch.mesh import fleet_mesh
+    from repro.core.mesh import fleet_mesh
 
     graphs = [build_random_cec(connected_er(12, 0.35, seed=20 + s), 3,
                                8.0, seed=s)

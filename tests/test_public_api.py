@@ -96,10 +96,8 @@ PINNED_ALL = [
     "make_bank", "get_cost", "resolve_cost",
     "UtilityFamily", "get_family", "fit_utilities", "OnlineFitter",
     "fixed_point_solve", "tune_etas",
-    "CECRouter", "InferenceEngine", "ServingSim",
-    "core", "configs", "topo", "kernels", "serve", "parallel",
-    "models", "train", "optim", "data", "launch", "roofline",
-    "obs",
+    "CECRouter",
+    "core", "configs", "topo", "kernels", "serve", "roofline", "obs",
 ]
 
 PINNED_SOLVER_CONFIG_FIELDS = (
@@ -174,3 +172,33 @@ def test_solver_core_is_the_only_update_site():
             if "jnp.exp(z)" in p.read_text()]
     assert hits == ["core/opt_baseline.py", "core/solver.py",
                     "kernels/control_megakernel.py"], hits
+
+
+# the control plane's packages; nothing they load lies outside them
+CONTROL_PLANE = ("core", "kernels", "topo", "obs", "serve", "configs")
+
+
+def test_control_plane_loads_only_itself():
+    """Importing the control plane (solver core, kernels, observability,
+    serving) in a fresh interpreter loads no ``repro`` module outside its
+    own packages."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import sys\n"
+            "import repro.core, repro.serve, repro.kernels, repro.obs\n"
+            "print('\\n'.join(m for m in sys.modules\n"
+            "                 if m.startswith('repro.')))\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.dirname(os.path.dirname(repro.__file__)),
+         env.get("PYTHONPATH", "")])
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True)
+    assert r.returncode == 0, r.stderr
+    loaded = r.stdout.split()
+    assert {m.split(".")[1] for m in loaded} >= {"core", "serve", "kernels",
+                                                 "obs"}
+    outside = [m for m in loaded if m.split(".")[1] not in CONTROL_PLANE]
+    assert not outside, outside

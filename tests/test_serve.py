@@ -1,119 +1,13 @@
-"""Serving plane: continuous-batching engine correctness + CEC router."""
-import dataclasses
-
+"""Serving plane: the CEC router."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_config
 from repro.core import build_random_cec, get_cost, total_cost
 from repro.core.routing import solve_routing
-from repro.models import model as M
-from repro.serve import CECRouter, InferenceEngine, Request, ServingSim
+from repro.serve import CECRouter
 from repro.topo import connected_er
-
-
-def _cfg():
-    return dataclasses.replace(get_config("smollm-135m", smoke=True),
-                               dtype="float32")
-
-
-def _solo(cfg, params, prompt, new, max_len=64):
-    """Sequential single-request reference with a roomy cache window."""
-    lg, cache = M.prefill(cfg, params, {"tokens": jnp.asarray(prompt)[None]},
-                          max_len=max_len)
-    out = [int(jnp.argmax(lg[0]))]
-    for _ in range(new - 1):
-        lg, cache = M.decode_step(
-            cfg, params, jnp.asarray([[out[-1]]], jnp.int32), cache)
-        out.append(int(jnp.argmax(lg[0])))
-    return out
-
-
-def test_continuous_batching_matches_sequential():
-    """Ragged slots (different arrival times/lengths) must produce the
-    same tokens as decoding each request alone."""
-    cfg = _cfg()
-    params = M.init(cfg, jax.random.PRNGKey(0))
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(0, cfg.vocab, size=n).astype(np.int32)
-               for n in (5, 9, 3)]
-
-    # sequential reference
-    def solo(prompt, new=6):
-        lg, cache = M.prefill(cfg, params, {"tokens": jnp.asarray(prompt)[None]},
-                              max_len=32)
-        out = [int(jnp.argmax(lg[0]))]
-        for _ in range(new - 1):
-            lg, cache = M.decode_step(
-                cfg, params, jnp.asarray([[out[-1]]], jnp.int32), cache)
-            out.append(int(jnp.argmax(lg[0])))
-        return out
-
-    want = [solo(p) for p in prompts]
-
-    # max_batch < #requests forces queueing → ragged slot reuse
-    eng2 = InferenceEngine(cfg, params, max_batch=2, max_len=32)
-    reqs = [Request(i, p, max_new_tokens=6) for i, p in enumerate(prompts)]
-    for r in reqs:
-        eng2.submit(r)
-    eng2.drain()
-    for r, w in zip(reqs, want):
-        assert r.output[:6] == w, (r.rid, r.output, w)
-
-
-def test_engine_serves_all_under_slot_pressure():
-    cfg = _cfg()
-    params = M.init(cfg, jax.random.PRNGKey(0))
-    eng = InferenceEngine(cfg, params, max_batch=2, max_len=24)
-    rng = np.random.default_rng(1)
-    reqs = [Request(i, rng.integers(0, cfg.vocab, 4).astype(np.int32),
-                    max_new_tokens=4) for i in range(5)]
-    for r in reqs:
-        eng.submit(r)
-    eng.drain()
-    assert all(r.done for r in reqs)
-    assert eng.tokens_served >= 5 * 3
-
-
-def test_engine_max_len_boundary():
-    """Prompt + generation at/over the cache window: every decode write
-    must stay inside the grafted window (the old ``>=`` check let a
-    window-filling prompt's first decode write one slot past it) and the
-    truncated tokens must match a roomy-window reference — corruption from
-    an out-of-window write would diverge them."""
-    cfg = _cfg()
-    params = M.init(cfg, jax.random.PRNGKey(0))
-    rng = np.random.default_rng(3)
-    L = 16
-    for S in (L - 3, L - 1, L):
-        prompt = rng.integers(0, cfg.vocab, S).astype(np.int32)
-        eng = InferenceEngine(cfg, params, max_batch=2, max_len=L)
-        req = Request(0, prompt, max_new_tokens=8)
-        eng.submit(req)
-        eng.drain()
-        # window capacity: prefill holds S entries and emits one token;
-        # each further token costs one cache write at index S+k
-        want_n = min(8, L - S + 1)
-        assert len(req.output) == want_n, (S, req.output)
-        assert req.output == _solo(cfg, params, prompt, want_n)
-
-
-def test_engine_rejects_oversized_prompt_and_caps_generation():
-    cfg = _cfg()
-    params = M.init(cfg, jax.random.PRNGKey(0))
-    rng = np.random.default_rng(4)
-    eng = InferenceEngine(cfg, params, max_batch=2, max_len=12)
-    with pytest.raises(ValueError):
-        eng.submit(Request(0, rng.integers(0, cfg.vocab, 13).astype(np.int32)))
-    # max_new_tokens=1 is satisfied by the prefill token alone — the old
-    # admit path still scheduled a decode step and over-generated
-    req = Request(1, rng.integers(0, cfg.vocab, 4).astype(np.int32),
-                  max_new_tokens=1)
-    eng.submit(req)
-    eng.drain()
-    assert len(req.output) == 1
 
 
 def test_cec_router_dispatch_consistency():
@@ -251,29 +145,6 @@ def test_router_under_churn_recovers_utility():
     assert post[-1] >= 0.95 * pre             # and it holds at the end
 
 
-def test_serving_sim_end_to_end():
-    """Engine traffic + fused router + scenario events in one loop: the
-    serving counterpart of run_scenario (what is benchmarked is what
-    serves)."""
-    from repro.core import NodeFail, Scenario
-
-    cfg = _cfg()
-    params = M.init(cfg, jax.random.PRNGKey(0))
-    sc = Scenario("fleet", horizon=6, topo_kwargs={"n": 12, "p": 0.35},
-                  n_sessions=3, mean_capacity=20.0, lam_total=12.0,
-                  events=(NodeFail(at=3, count=1, seed=4),))
-    sim = ServingSim(sc, cfg=cfg, params=params, seed=0,
-                     requests_per_interval=4, engine_steps_per_interval=6,
-                     prompt_len=4, max_new_tokens=3, max_batch=2, max_len=24)
-    rep = sim.run()
-    assert rep.utility.shape == (6,) and np.isfinite(rep.utility).all()
-    assert rep.tokens_served > 0 and rep.tokens.sum() == rep.tokens_served
-    assert [k for _, k in rep.events] == ["NodeFail"]
-    # admission split stays feasible through the event
-    np.testing.assert_allclose(rep.lam.sum(-1), 12.0, rtol=1e-4)
-    assert (rep.goodput > 0).all()
-
-
 def test_router_migrates_to_learned_and_drift_demotes():
     """grad_policy="auto": the router samples until the fitter's holdout
     clears, migrates to learned gradients (1 measured admission per
@@ -344,3 +215,55 @@ def test_router_rejects_unknown_grad_policy():
     g = build_random_cec(connected_er(10, 0.35, seed=2), 3, 20.0, seed=0)
     with pytest.raises(ValueError, match="grad_policy"):
         CECRouter(g, lam_total=12.0, grad_policy="leraned")
+
+
+@pytest.mark.parametrize("event", ["NodeFail", "DemandShift"])
+@pytest.mark.parametrize("layout", ["dense", "edges"])
+def test_router_in_the_loop(layout, event):
+    """A scenario drives the live router on either graph layout, against a
+    synthetic measured utility: the event fires at its interval, Λ stays
+    on its box-simplex every interval, and utility climbs again after the
+    event to at least 95% of its level before it."""
+    import contextlib
+
+    from repro.core import (CECGraphSparse, DemandShift, NodeFail, Scenario,
+                            dispatch, event_schedule, initial_state,
+                            make_bank)
+
+    at = 8
+    ev = {"NodeFail": NodeFail(at=at, count=1, seed=4),
+          "DemandShift": DemandShift(at=at, lam_total=18.0)}[event]
+    sc = Scenario("fleet", horizon=24, topo_kwargs={"n": 12, "p": 0.35},
+                  mean_capacity=20.0, lam_total=12.0, events=(ev,))
+    state = initial_state(sc, seed=0)
+    bank = make_bank("log", 3, seed=0)
+
+    def measured(lams):                       # batched measured utility
+        lams = jnp.atleast_2d(jnp.asarray(lams))
+        return np.asarray(jax.vmap(bank.total)(lams))
+
+    layout_ctx = (dispatch.sparse_dispatch(1) if layout == "edges"
+                  else contextlib.nullcontext())
+    with layout_ctx:
+        router = CECRouter(state.graph(), lam_total=sc.lam_total)
+        schedule = {t: evs for t, evs in event_schedule(sc) if evs}
+        fired, utilities = [], []
+        for t in range(sc.horizon):
+            for e in schedule.get(t, ()):
+                state = router.apply_scenario_event(state, e)
+                fired.append((t, e.kind))
+            assert isinstance(router.graph, CECGraphSparse) == \
+                (layout == "edges")
+            rec = router.control_step(measured)
+            lam, total = np.asarray(rec["lam"]), router.lam_total
+            np.testing.assert_allclose(lam.sum(), total, rtol=1e-5)
+            assert (lam >= router.delta - 1e-4).all()
+            assert (lam <= total - router.delta + 1e-4).all()
+            utilities.append(rec["utility"])
+    assert fired == [(at, event)]
+    assert router.history[at] == {"event": event, "at": at}
+    assert router.lam_total == state.lam_total
+    u = np.asarray(utilities)
+    pre, post = u[at - 3:at].mean(), u[at:]
+    assert post[-1] > post[0]                 # re-converges after it
+    assert post[-1] >= 0.95 * pre             # and ends near the pre level

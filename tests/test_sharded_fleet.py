@@ -35,10 +35,9 @@ from repro.core.batch import (CECGraphBatch, CECGraphSparseBatch, run_batch,
 from repro.core.graph import build_random_cec, sparsify
 from repro.core.solver import SolverConfig
 from repro.core.utility import make_bank
-from repro.launch.mesh import fleet_mesh
-from repro.parallel.sharding import (FLEET_AXIS, fleet_axis, fleet_padded_size,
-                                     fleet_spec, fleet_specs, pad_fleet,
-                                     unpad_fleet)
+from repro.core.mesh import (FLEET_AXIS, fleet_axis, fleet_mesh,
+                             fleet_padded_size, fleet_spec, fleet_specs,
+                             pad_fleet, unpad_fleet)
 from repro.topo import make_fleet
 
 CONFIG = SolverConfig(method="single", delta=0.5, eta_outer=0.05,
@@ -183,7 +182,7 @@ from repro.core.batch import (CECGraphBatch, CECGraphSparseBatch, run_batch,
 from repro.core.graph import build_random_cec, sparsify
 from repro.core.solver import SolverConfig
 from repro.core.utility import make_bank
-from repro.launch.mesh import fleet_mesh
+from repro.core.mesh import fleet_mesh
 from repro.topo import make_fleet
 
 cfg = SolverConfig(method="single", delta=0.5, eta_outer=0.05,
@@ -232,7 +231,7 @@ def test_multidevice_scenario_timeline():
 import jax, jax.numpy as jnp
 assert jax.device_count() == 8
 from repro.core.scenario import named_scenarios, run_scenario
-from repro.launch.mesh import fleet_mesh
+from repro.core.mesh import fleet_mesh
 
 sc = named_scenarios(horizon=10, n=8)["link_churn"]
 ref = run_scenario(sc, seeds=(0, 1, 2))
@@ -256,7 +255,7 @@ from repro.core.batch import CECGraphBatch, run_batch, run_batch_sharded
 from repro.core.graph import build_random_cec
 from repro.core.solver import SolverConfig
 from repro.core.utility import make_bank
-from repro.launch.mesh import fleet_mesh
+from repro.core.mesh import fleet_mesh
 from repro.topo import make_fleet
 
 cfg = SolverConfig(method="single", delta=0.5, eta_outer=0.05,
@@ -338,7 +337,7 @@ def test_fleet_specs_tree_matches_leaf_ranks(ndims, shard):
 
 def test_shard_map_compat_identity_on_one_device():
     """The compat shim runs the body and honours specs on any jax version."""
-    from repro.parallel.collectives import shard_map_compat
+    from repro.core.mesh import shard_map_compat
 
     mesh = fleet_mesh(n_devices=1)
     x = jnp.arange(12.0).reshape(4, 3)

@@ -9,6 +9,7 @@ equivalence, the marginal recursion's light/heavy split of the out-rows,
 and the Pallas sparse-kernel dispatch.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -24,11 +25,15 @@ from repro.core import (CECGraphSparse, CECGraphSparseBatch, SparsePhi,
                         get_cost, make_bank, omd_step, pad_sparse_graph,
                         propagate, solve_jowr, solve_routing,
                         solve_routing_batch, sparsify, total_cost)
+from repro.core import serving_defaults
+from repro.core import solver as S
 from repro.core import sparse as sp
 from repro.core.flow import cost_and_state
 from repro.core.graph import draw_instance
 from repro.core.marginal import marginals
-from repro.topo import connected_er, power_law
+from repro.core.problem import Problem
+from repro.topo import (abilene, balanced_tree, connected_er, fog, geant,
+                        grid_2d, power_law, random_geometric)
 
 from conftest import random_phi
 
@@ -232,6 +237,120 @@ def test_solve_routing_trajectory_parity(seed):
     _, tr_s = solve_routing(gs, COST, lam, gs.uniform_phi(), 1.0, 40)
     np.testing.assert_allclose(np.asarray(tr_d), np.asarray(tr_s),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the named topologies, stage by stage
+# ---------------------------------------------------------------------------
+
+# the paper's topologies and the fleet generators at a size a CPU run
+# affords; the Barabási–Albert draws are hub-skewed, so the marginal
+# recursion's light/heavy split of the out-rows engages on them
+NAMED_TOPOLOGIES = {
+    "abilene": abilene, "geant": geant, "fog": fog,
+    "balanced_tree": balanced_tree,
+    "grid_2d": lambda: grid_2d(64),
+    "rgg-s0": lambda: random_geometric(64, seed=0),
+    "rgg-s1": lambda: random_geometric(64, seed=1),
+    "power_law-m2": lambda: power_law(300, 2),
+    "power_law-m3": lambda: power_law(300, 3),
+}
+STAGES = ("flow_cost", "marginals_exp", "marginals_mm1", "solver_step",
+          "alive")
+
+
+@functools.lru_cache(maxsize=None)
+def _named(name):
+    """(adjacency, instance draw, dense graph) of one named topology."""
+    adj = NAMED_TOPOLOGIES[name]()
+    inst = draw_instance(adj, 3, 10.0, 0)
+    return adj, inst, inst.graph
+
+
+def _one_dead(adj, inst):
+    """The instance with the first node (in a seeded order) whose loss
+    leaves it feasible marked dead."""
+    from repro.core import InfeasibleTopology
+
+    for node in np.random.default_rng(0).permutation(adj.shape[0]):
+        alive = np.ones(adj.shape[0], bool)
+        alive[node] = False
+        try:
+            return build_augmented(adj, inst.deploy, inst.link_capacity,
+                                   inst.compute_capacity, alive=alive)
+        except InfeasibleTopology:
+            continue
+    raise AssertionError("no single node can fail feasibly")
+
+
+@jax.jit
+def _flow_cost(graph, phi, lam):
+    return propagate(graph, phi, lam), total_cost(graph, COST, phi, lam)
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _marginals(graph, cost_name, phi, lam):
+    cost = get_cost(cost_name)
+    _, t, F = cost_and_state(graph, cost, phi, lam)
+    return marginals(graph, cost, phi, t, F)
+
+
+_STEP = jax.jit(S.step, static_argnums=1)
+
+
+def _assert_marginals_agree(g, gs, got_d, got_s):
+    delta_d, dDdr_d = got_d
+    delta_s, dDdr_s = got_s
+    np.testing.assert_allclose(np.asarray(dDdr_d), np.asarray(dDdr_s),
+                               rtol=1e-5, atol=1e-5)
+    m = np.asarray(g.out_mask) > 0
+    np.testing.assert_allclose(np.asarray(delta_d)[m],
+                               np.asarray(sp.phi_to_dense(gs, delta_s))[m],
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("stage", STAGES)
+@pytest.mark.parametrize("name", sorted(NAMED_TOPOLOGIES))
+def test_named_topology_parity(name, stage):
+    """Each stage of the solver agrees across layouts on the named
+    topologies, at the single-step parity tolerances: flow and cost,
+    the marginals under two costs, one ``solver.step``, and flow with
+    one node dead."""
+    adj, inst, g = _named(name)
+    if stage == "alive":
+        g = _one_dead(adj, inst)
+    gs, phi, phis = _sparse_pair(g, 0)
+    lam = _lam(g, 0)
+    if stage in ("flow_cost", "alive"):
+        t_d, D_d = _flow_cost(g, phi, lam)
+        t_s, D_s = _flow_cost(gs, phis, lam)
+        np.testing.assert_allclose(np.asarray(t_d), np.asarray(t_s),
+                                   rtol=1e-5, atol=1e-5)
+        if stage == "flow_cost":
+            np.testing.assert_allclose(float(D_d), float(D_s), rtol=1e-5)
+    elif stage.startswith("marginals_"):
+        cost_name = stage.removeprefix("marginals_")
+        _assert_marginals_agree(g, gs, _marginals(g, cost_name, phi, lam),
+                                _marginals(gs, cost_name, phis, lam))
+    else:
+        bank = make_bank("log", 3, seed=0)
+        config = serving_defaults()
+        out = []
+        for graph in (g, gs):
+            problem = Problem.create(graph, bank, lam_total=60.0)
+            state = S.init(problem, config, phi0=phi)
+            task_u = jax.vmap(bank.total)(
+                S.perturbed_allocations(state.lam, config.delta))
+            out.append(_STEP(problem, config, state, task_u))
+        (st_d, info_d), (st_s, info_s) = out
+        np.testing.assert_allclose(np.asarray(st_d.lam),
+                                   np.asarray(st_s.lam), rtol=1e-5,
+                                   atol=1e-5)
+        np.testing.assert_allclose(float(info_d.cost), float(info_s.cost),
+                                   rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(st_d.phi),
+                                   np.asarray(sp.phi_to_dense(gs, st_s.phi)),
+                                   rtol=1e-5, atol=1e-5)
 
 
 # ---------------------------------------------------------------------------
